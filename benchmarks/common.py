@@ -8,8 +8,12 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro.data.svm_datasets import SVMDataset, make_dataset
 from repro.kernels.hinge_subgrad.ops import default_interpret
+
+# every benchmark CLI imports this module: one persistent compile cache
+compile_cache.enable()
 
 # scale factors keep wall time sane on one CPU core while preserving each
 # dataset's (d, sparsity, lambda) signature; row counts stay in the thousands.

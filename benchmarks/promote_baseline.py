@@ -36,8 +36,8 @@ from benchmarks.check_regression import (WALLCLOCK_LEAVES, WALLCLOCK_PARENTS,
                                          fingerprint_slug)
 
 # The fingerprint of CI's pinned runner class (.github/workflows/ci.yml:
-# runs-on: ubuntu-24.04, python 3.11, JAX_PLATFORMS=cpu,
-# REPRO_PALLAS_INTERPRET=1, 4-core hosted image).
+# runs-on: ubuntu-24.04, python 3.11, JAX_PLATFORMS=cpu so the kernels
+# interpret, 4-core hosted image).
 HOSTED_FINGERPRINT = {
     "os": "linux", "machine": "x86_64", "python": "3.11", "backend": "cpu",
     "pallas_interpret": 1, "cpu_count": 4,

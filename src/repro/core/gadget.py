@@ -804,7 +804,8 @@ def _make_device_train(cfg: GadgetConfig, m: int, n_i: int, d: int,
                 mass_tr, snaps, tele_out, final_obj, bad)
 
     # Buffer donation is a no-op (with a warning) on CPU — only request it
-    # where the runtime honors it.
+    # where the runtime honors it. W0 / W_sum0 are fresh zeros made by
+    # _prepare_device_train, so no caller holds them.
     donate = (6, 7) if jax.default_backend() != "cpu" else ()
     return jax.jit(train, donate_argnums=donate)
 
@@ -1158,8 +1159,10 @@ def _make_segment_train(cfg: GadgetConfig, m: int, n_i: int, d: int,
         return base + (dis, mass_min, mass_max,
                        jnp.sum(jnp.where(act, drops, 0)))
 
-    donate = (6, 7) if jax.default_backend() != "cpu" else ()
-    return jax.jit(segment, donate_argnums=donate)
+    # No buffer donation: W / W_sum are the arrays the previous segment
+    # yielded (or the caller's resume state), which the caller may still
+    # hold — donating them would delete them under the caller on a TPU.
+    return jax.jit(segment)
 
 
 def gadget_train_stream(
@@ -1479,8 +1482,8 @@ def make_gadget_mesh_step(cfg: GadgetConfig, axis_sizes: dict[str, int],
     ``sparse_block_bound`` as the prefetch grid cap — derive it on host with
     ``formats.minibatch_block_bound`` over the full planes so every shard
     traces the same grid), and only the dense w crosses the mesh in gossip.
-    Kernel-backed steps need ``shard_map(..., check_rep=False)`` — jax has no
-    replication rule for ``pallas_call`` yet (tests pin this).
+    Kernel-backed steps need ``jax.shard_map(..., check_vma=False)`` — jax
+    has no replication rule for ``pallas_call`` yet (tests pin this).
 
     ``cfg.faults`` injects the same fault model as the simulator path, as
     masked ``ppermute`` sends: each round every node draws a fail bit from

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import topology as topo
+from repro.core.svm_objective import F32
 
 Pytree = Any
 
@@ -102,11 +103,11 @@ class PushSumSim:
 
         def _mix(v):
             flat = v.reshape(self.n, -1).astype(jnp.float32)
-            out = B.T @ flat
+            out = jnp.matmul(B.T, flat, precision=F32)
             return out.reshape(v.shape).astype(v.dtype)
 
         values = jax.tree.map(_mix, state.values)
-        weight = B.T @ state.weight
+        weight = jnp.matmul(B.T, state.weight, precision=F32)
         return PushSumState(values, weight)
 
     def run(self, values: Pytree, n_rounds: int, t0: int = 0) -> PushSumState:
@@ -132,7 +133,8 @@ def mix_rounds(values: jax.Array, weight: jax.Array, B_rounds: jax.Array):
 
     def body(carry, B):
         v, w = carry
-        return (B.T @ v, B.T @ w), None
+        return (jnp.matmul(B.T, v, precision=F32),
+                jnp.matmul(B.T, w, precision=F32)), None
 
     (v, w), _ = jax.lax.scan(body, (values, weight), B_rounds)
     return v, w
@@ -150,7 +152,7 @@ def collapse_rounds(B_rounds: jax.Array) -> jax.Array:
     """
 
     def body(P, B):
-        return B.T @ P, None
+        return jnp.matmul(B.T, P, precision=F32), None
 
     P0 = jnp.eye(B_rounds.shape[-1], dtype=B_rounds.dtype)
     P, _ = jax.lax.scan(body, P0, B_rounds)
@@ -162,7 +164,8 @@ def mix_collapsed(values: jax.Array, weight: jax.Array, P: jax.Array):
     weights: one matmul per tensor, replacing the R-round ``mix_rounds`` scan.
     ``P`` comes from :func:`collapse_rounds` or a precomputed
     ``topology.build_product_stack`` slice."""
-    return P @ values, P @ weight
+    return (jnp.matmul(P, values, precision=F32),
+            jnp.matmul(P, weight, precision=F32))
 
 
 # ---------------------------------------------------------------------------
@@ -220,11 +223,7 @@ def push_sum_round(
     undeliverable share back into the local mass (exact conservation),
     ``drop="message"`` loses it. ``dead`` freezes this shard's values and
     weight entirely — a crashed node neither mixes nor accumulates."""
-    # jax.lax.axis_size only exists on newer jax; psum of 1 is the portable
-    # spelling (constant-folded at trace time, no collective is emitted)
-    axis_size = getattr(jax.lax, "axis_size", None)
-    n = int(axis_size(rnd.axis) if axis_size is not None
-            else jax.lax.psum(1, rnd.axis))
+    n = jax.lax.axis_size(rnd.axis)
     if n == 1:
         return state
     pairs = _ring_perm(n, rnd.hop)

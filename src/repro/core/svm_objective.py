@@ -11,6 +11,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Full f32 contractions: a TPU's default f32 matmul is one bf16 pass, which
+# would round margins and gradients (no effect on the CPU backend).
+F32 = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "hinge_loss",
     "primal_objective",
@@ -25,7 +29,7 @@ __all__ = [
 
 def hinge_loss(w: jax.Array, X: jax.Array, y: jax.Array) -> jax.Array:
     """Mean hinge loss (1/N) sum max(0, 1 - y <w, x>). X: (N, d), y: (N,) in {-1,+1}."""
-    margins = y * (X @ w)
+    margins = y * jnp.matmul(X, w, precision=F32)
     return jnp.mean(jnp.maximum(0.0, 1.0 - margins))
 
 
@@ -43,7 +47,7 @@ def primal_objective_masked(w: jax.Array, X: jax.Array, y: jax.Array,
     unmasked mean. ``total`` is the true sample count (sum of per-node
     n_counts), so for an all-true mask this reduces to ``primal_objective``.
     """
-    margins = y * (X @ w)
+    margins = y * jnp.matmul(X, w, precision=F32)
     hinge = jnp.sum(jnp.where(valid, jnp.maximum(0.0, 1.0 - margins), 0.0)) / total
     return 0.5 * lam * jnp.dot(w, w) + hinge
 
@@ -65,9 +69,9 @@ def hinge_subgradient(w: jax.Array, X: jax.Array, y: jax.Array) -> jax.Array:
 
     Returns (1/B) sum_{j: margin_j < 1} (-y_j x_j), shape (d,).
     """
-    margins = y * (X @ w)
+    margins = y * jnp.matmul(X, w, precision=F32)
     viol = (margins < 1.0).astype(X.dtype)
-    return -(X.T @ (viol * y)) / X.shape[0]
+    return -jnp.matmul(X.T, viol * y, precision=F32) / X.shape[0]
 
 
 def pegasos_update(w: jax.Array, X: jax.Array, y: jax.Array, lam: float, t: jax.Array) -> jax.Array:
@@ -90,4 +94,5 @@ def project_ball(w: jax.Array, lam: float) -> jax.Array:
 
 
 def accuracy(w: jax.Array, X: jax.Array, y: jax.Array) -> jax.Array:
-    return jnp.mean((jnp.sign(X @ w) == y).astype(jnp.float32))
+    return jnp.mean((jnp.sign(jnp.matmul(X, w, precision=F32)) == y)
+                    .astype(jnp.float32))

@@ -21,7 +21,10 @@ path too.
 """
 from __future__ import annotations
 
+import copy
+import os
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 import numpy as np
 
@@ -101,17 +104,7 @@ def _sample_cols(rng: np.random.Generator, n: int, nnz: int, d: int,
     if nnz >= d:
         return np.tile(np.arange(d, dtype=np.int64), (n, 1))
     if skew > 0.0:
-        inv_w = np.arange(1, d + 1, dtype=np.float32) ** np.float32(skew)
-        chunk = max(1, (1 << 25) // d)
-        out = np.empty((n, nnz), np.int64)
-        for s in range(0, n, chunk):
-            e = min(n, s + chunk)
-            u = rng.random((e - s, d), dtype=np.float32)
-            with np.errstate(divide="ignore"):  # u=0 → -inf: never selected
-                np.log(u, out=u)   # -E ~ -Exp(1)
-            u *= inv_w             # key = -E/w: keep the nnz *largest* -keys
-            out[s:e] = np.argpartition(u, d - nnz, axis=1)[:, d - nnz:]
-        return out
+        return _zipf_race(rng, n, nnz, d, skew)
     if nnz * nnz <= d:
         cols = rng.integers(0, d, size=(n, nnz))
         bad = np.arange(n)
@@ -131,6 +124,63 @@ def _sample_cols(rng: np.random.Generator, n: int, nnz: int, d: int,
         e = min(n, s + chunk)
         r = rng.random((e - s, d), dtype=np.float32)
         out[s:e] = np.argpartition(r, nnz, axis=1)[:, :nnz]
+    return out
+
+
+def _zipf_race(rng: np.random.Generator, n: int, nnz: int, d: int,
+               skew: float) -> np.ndarray:
+    """The skewed branch of :func:`_sample_cols`: per row, the nnz largest
+    keys ``log(U_r)·(r+1)^skew`` (= -E_r/w_r) over U ~ Uniform[0, 1) (n, d)
+    f32 — U exactly what ``rng.random((n, d), float32)`` would draw, and
+    ``rng`` left where that draw would leave it.
+
+    Full CCAT draws 37 G uniforms, so the rows are split over a thread pool
+    (numpy releases the GIL in the fill, the ufuncs and the partition): PCG64
+    emits two f32 per step, so with d even the worker starting at row a draws
+    from a copy of the generator advanced a·d/2 steps. Each worker fills the
+    same two (chunk, d) buffers chunk after chunk and allocates nothing per
+    chunk — a host that is slow to take freed pages back (a sandboxed VM)
+    would otherwise count every chunk's scratch against its memory.
+
+    The selection packs each key's f32 bits above its column id into one
+    int64 and partitions in place: keys are ≤ 0, so the largest keys have
+    the smallest bit patterns, and an exact tie goes to the lower column.
+    """
+    inv_w = np.arange(1, d + 1, dtype=np.float32) ** np.float32(skew)
+    col_ids = np.arange(d, dtype=np.int64)
+    chunk = max(1, (1 << 23) // d)
+    out = np.empty((n, nnz), np.int64)
+
+    def race(a, b, gen):
+        u = np.empty((min(chunk, b - a), d), np.float32)
+        packed = np.empty(u.shape, np.int64)
+        for s in range(a, b, chunk):
+            uu, pk = u[:min(b, s + chunk) - s], packed[:min(b, s + chunk) - s]
+            gen.random(out=uu, dtype=np.float32)
+            with np.errstate(divide="ignore"):  # u=0 → -inf: never selected
+                np.log(uu, out=uu)              # -E ~ -Exp(1)
+            np.multiply(uu, inv_w, out=uu)      # key = -E/w
+            np.copyto(pk, uu.view(np.int32))
+            np.left_shift(pk, 32, out=pk)
+            np.bitwise_or(pk, col_ids, out=pk)
+            pk.partition(nnz - 1, axis=1)
+            np.bitwise_and(pk[:, :nnz], 0xFFFFFFFF, out=out[s:s + len(pk)])
+
+    bitgen = rng.bit_generator
+    workers = min(8, os.cpu_count() or 1, -(-n // chunk))
+    if (workers == 1 or d % 2 or not isinstance(bitgen, np.random.PCG64)
+            or bitgen.state["has_uint32"]):
+        race(0, n, rng)
+        return out
+    bounds = np.linspace(0, n, workers + 1).astype(int)
+    gens = []
+    for a in bounds[:-1]:
+        g = copy.deepcopy(bitgen)
+        g.advance(int(a) * d // 2)
+        gens.append(np.random.Generator(g))
+    with ThreadPoolExecutor(workers) as pool:
+        list(pool.map(race, bounds[:-1], bounds[1:], gens))
+    bitgen.advance(n * d // 2)
     return out
 
 

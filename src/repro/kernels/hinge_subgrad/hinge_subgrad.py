@@ -18,10 +18,18 @@ and stays in VMEM across both phases, and margins → violator coefficients →
 gradient → the Pegasos axpy never touch HBM — only w_half is written back.
 One kernel launch per GADGET iteration instead of 2m.
 
+Layout: Mosaic requires every block's last two dims to be (8, 128)
+multiples or the array's own extents, so vectors travel as 2-D slabs — a
+weight vector as a (1, d) row (per node: an (m, 1, d) array whose leading
+axis the grid walks), per-row quantities (y, margins, coefficients) as
+(B, 1) columns. Both mat-vecs are VPU multiply-and-reduce (lanes for X w,
+sublanes for X^T c): exact f32 on the chip, with no rank-1 MXU operand.
+The public functions take and return the natural shapes and do the
+reshapes outside the kernel.
+
 The ball projection needs a global ||w_half|| reduction and lives in the
-ops.py wrapper (O(d), bandwidth-trivial). Block shapes default to MXU/VREG
-friendly multiples of (8, 128); d and B are padded by the wrapper when
-needed.
+ops.py wrapper (O(d), bandwidth-trivial). d and B are padded by the wrapper
+to block multiples when needed.
 """
 from __future__ import annotations
 
@@ -29,8 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["margins", "grad_update", "fleet_half_step",
            "DEFAULT_BLK_B", "DEFAULT_BLK_D"]
@@ -46,7 +52,7 @@ def _margins_kernel(x_ref, w_ref, y_ref, m_ref, acc):
     def _():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += x_ref[...] @ w_ref[...]
+    acc[...] += jnp.sum(x_ref[...] * w_ref[...], axis=1, keepdims=True)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
@@ -56,34 +62,36 @@ def _margins_kernel(x_ref, w_ref, y_ref, m_ref, acc):
 def margins(X: jax.Array, w: jax.Array, y: jax.Array, *,
             blk_b: int = DEFAULT_BLK_B, blk_d: int = DEFAULT_BLK_D,
             interpret: bool = False) -> jax.Array:
-    """y * (X @ w) via the blocked mat-vec kernel. X: (B, d)."""
+    """y * (X @ w) via the blocked mat-vec kernel. X: (B, d), w: (d,),
+    y: (B,) → (B,)."""
     B, d = X.shape
     blk_b, blk_d = min(blk_b, B), min(blk_d, d)
     assert B % blk_b == 0 and d % blk_d == 0, "wrapper must pad"
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _margins_kernel,
         grid=(B // blk_b, d // blk_d),
         in_specs=[
             pl.BlockSpec((blk_b, blk_d), lambda i, j: (i, j)),
-            pl.BlockSpec((blk_d,), lambda i, j: (j,)),
-            pl.BlockSpec((blk_b,), lambda i, j: (i,)),
+            pl.BlockSpec((1, blk_d), lambda i, j: (0, j)),
+            pl.BlockSpec((blk_b, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((blk_b,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((blk_b,), jnp.float32)],
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((blk_b, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((blk_b, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(X, w, y)
+    )(X, w.reshape(1, d), y.reshape(B, 1))
+    return out.reshape(B)
 
 
 def _fleet_kernel(x_ref, w_ref, y_ref, mask_ref, scal_ref, o_ref):
     x = x_ref[0]       # (B, d) — the node's minibatch tile, resident in VMEM
-    w = w_ref[0]       # (d,)
-    yv = y_ref[0]      # (B,)
-    m = yv * (x @ w)                                   # phase 1: margins
+    w = w_ref[0]       # (1, d)
+    yv = y_ref[0]      # (B, 1)
+    m = yv * jnp.sum(x * w, axis=1, keepdims=True)     # phase 1: margins
     coeff = jnp.where(m < 1.0, yv, 0.0) * mask_ref[...]  # violator selection
-    g = coeff @ x                                      # phase 2: X^T c, same tile
+    g = jnp.sum(coeff * x, axis=0, keepdims=True)      # phase 2: X^T c, same tile
     o_ref[0] = (1.0 - scal_ref[0]) * w + scal_ref[1] * g
 
 
@@ -103,21 +111,22 @@ def fleet_half_step(X: jax.Array, W: jax.Array, y: jax.Array,
     round-trips through HBM. The wrapper bounds B*d so the tile fits VMEM.
     """
     m, B, d = X.shape
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _fleet_kernel,
         grid=(m,),
         in_specs=[
             pl.BlockSpec((1, B, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((B,), lambda i: (0,)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, B, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((B, 1), lambda i: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(X, W, y, row_mask, scal)
+    )(X, W.reshape(m, 1, d), y.reshape(m, B, 1), row_mask.reshape(B, 1), scal)
+    return out.reshape(m, d)
 
 
 def _update_kernel(x_ref, w_ref, c_ref, scal_ref, o_ref, gacc):
@@ -128,7 +137,7 @@ def _update_kernel(x_ref, w_ref, c_ref, scal_ref, o_ref, gacc):
         gacc[...] = jnp.zeros_like(gacc)
 
     # g_d += X[b_blk, d_blk]^T c[b_blk]
-    gacc[...] += c_ref[...] @ x_ref[...]
+    gacc[...] += jnp.sum(c_ref[...] * x_ref[...], axis=0, keepdims=True)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
@@ -143,24 +152,25 @@ def grad_update(X: jax.Array, w: jax.Array, coeff: jax.Array, scal: jax.Array, *
     """w_half = (1 - scal[0]) w + scal[1] * (coeff @ X).
 
     coeff: (B,) = 1[margin<1] * y (violator selection, computed by wrapper);
-    scal: (2,) = [lam*alpha, alpha/B] in SMEM.
+    scal: (2,) = [lam*alpha, alpha/B] in SMEM. Returns (d,).
     """
     B, d = X.shape
     blk_b, blk_d = min(blk_b, B), min(blk_d, d)
     assert B % blk_b == 0 and d % blk_d == 0, "wrapper must pad"
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _update_kernel,
         grid=(d // blk_d, B // blk_b),
         in_specs=[
             pl.BlockSpec((blk_b, blk_d), lambda i, j: (j, i)),
-            pl.BlockSpec((blk_d,), lambda i, j: (i,)),
-            pl.BlockSpec((blk_b,), lambda i, j: (j,)),
+            pl.BlockSpec((1, blk_d), lambda i, j: (0, i)),
+            pl.BlockSpec((blk_b, 1), lambda i, j: (j, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((blk_d,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((blk_d,), jnp.float32)],
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((1, blk_d), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, blk_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(X, w, coeff, scal)
+    )(X, w.reshape(1, d), coeff.reshape(B, 1), scal)
+    return out.reshape(d)

@@ -14,7 +14,6 @@ three wrappers share.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -40,14 +39,9 @@ FLEET_TILE_BUDGET_BYTES = 4 * 1024 * 1024
 
 
 def default_interpret() -> bool:
-    """True when the Pallas kernels should run in interpret mode.
-
-    ``REPRO_PALLAS_INTERPRET=0/1`` overrides; otherwise interpret everywhere
-    except a real TPU backend, so CPU CI exercises the kernel code path.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
-    if env:  # set-but-empty falls through to the auto default
-        return env.lower() not in ("0", "false", "off", "no")
+    """True when the Pallas kernels should run in interpret mode: exactly
+    when the backend is not a TPU, so CPU CI exercises the kernel code path
+    and a TPU always compiles the kernels."""
     return jax.default_backend() != "tpu"
 
 
@@ -249,8 +243,9 @@ def fleet_half_step(W: jax.Array, X: jax.Array, y: jax.Array, *, lam: float,
     return W_half.astype(W.dtype)
 
 
-# Cap on the (B·k, blk_d) f32 one-hot each sparse-kernel program materializes
-# in VMEM; the wrapper shrinks blk_d (lane-multiple floor) to stay under it.
+# Cap on the (blk_d, k) f32 one-hot each sparse-kernel program materializes
+# in VMEM per minibatch row; the wrapper shrinks blk_d (lane-multiple floor)
+# to stay under it.
 ELL_ONEHOT_BUDGET = 4 * 1024 * 1024
 
 # Touched-block (scalar-prefetch) schedule block width: the 128-lane minimum,
@@ -260,9 +255,9 @@ ELL_ONEHOT_BUDGET = 4 * 1024 * 1024
 ELL_PREFETCH_BLK_D = DEFAULT_BUCKET_BLK_D
 
 
-def _ell_blk_d(d_pad: int, Bk: int) -> int:
+def _ell_blk_d(d_pad: int, k_pad: int) -> int:
     blk = min(S.DEFAULT_BLK_D_SPARSE, d_pad)
-    while blk > 128 and Bk * blk * 4 > ELL_ONEHOT_BUDGET:
+    while blk > 128 and k_pad * blk * 4 > ELL_ONEHOT_BUDGET:
         # shrink in 128-lane multiples only — Mosaic block shapes require it
         blk = max(128, blk // 2 // 128 * 128)
     return blk
@@ -315,8 +310,7 @@ def resolve_ell_schedule(schedule: str, *, B: int, k: int, d: int,
     if schedule not in ("auto", "prefetch", "sweep"):
         raise ValueError(f"unknown ELL schedule {schedule!r}")
     kp = -(-max(k, 1) // 128) * 128
-    Bp = -(-B // 8) * 8
-    sweep_blk = _ell_blk_d(-(-d // 128) * 128, Bp * kp)
+    sweep_blk = _ell_blk_d(-(-d // 128) * 128, kp)
     if schedule == "sweep":
         return "sweep", (blk_d or sweep_blk), 0
     pref_blk = blk_d or ELL_PREFETCH_BLK_D
@@ -396,10 +390,11 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         Wp = _pad_to(W.astype(jnp.float32), (n_d_blocks + 1) * blk_d, 1)
         margins = S.ell_margins_prefetch(colsP, valsP, Wp, yp, bids,
                                          blk_d=blk_d, n_d_blocks=n_d_blocks,
-                                         interpret=interpret)
+                                         n_rows=B, interpret=interpret)
         coeff = jnp.where(margins < 1.0, yp, 0.0)
         G = S.ell_grad_update_prefetch(colsP, valsP, coeff, bids, blk_d=blk_d,
-                                       n_d_blocks=n_d_blocks, interpret=interpret)
+                                       n_d_blocks=n_d_blocks, n_rows=B,
+                                       interpret=interpret)
         # fold buckets into the axpy: decay everywhere, scatter-add the live
         # buckets (sentinel buckets index past d_pad → dropped, and are zero)
         flat = (bids[:, :, None] * blk_d
@@ -410,13 +405,13 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         )(Wp[:, :d_pad], G.reshape(m, -1), flat)[:, :d]
     else:
         Wp = _pad_to(W.astype(jnp.float32), blk_d, 1)
-        margins = S.ell_margins(colsP, valsP, Wp, yp, blk_d=blk_d,
+        margins = S.ell_margins(colsP, valsP, Wp, yp, blk_d=blk_d, n_rows=B,
                                 interpret=interpret)
         # pad rows carry y=0 ⇒ coefficient 0 (padded_row_mask invariant):
         # inert in the scatter though their margin 0 selects as a violator
         coeff = jnp.where(margins < 1.0, yp, 0.0)
         W_half = S.ell_grad_update(colsP, valsP, Wp, coeff, scal, blk_d=blk_d,
-                                   interpret=interpret)[:, :d]
+                                   n_rows=B, interpret=interpret)[:, :d]
     if project:
         W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
     return W_half.astype(W.dtype)

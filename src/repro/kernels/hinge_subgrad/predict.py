@@ -14,11 +14,12 @@ paper's binary SVMs, C > 1 for the one-vs-rest multiclass extension), then
     (B, k): the *query-side* reuse of the training prefetch machinery. A
     compact touched-block-id map (repro.sparse.formats.block_map over the
     query batch) rides in as a ``PrefetchScalarGridSpec`` scalar operand, the
-    W ``index_map`` DMAs exactly one live (C, blk_d) block per program, and
-    the in-block gather is the same one-hot contraction as the training
-    kernels (``sparse._onehot_gather``): onehot @ W_blk^T gives every query
-    entry its per-class weight rows in one MXU pass. Sentinel slots alias
-    the all-zero pad block appended after W's last real block and skip the
+    W ``index_map`` DMAs exactly one live (C, blk_d) block per program. The
+    in-block step densifies each query row's entries that land in the block
+    with the training kernels' transposed one-hot (``sparse.onehot_t``) —
+    a (B, blk_d) slab of the query rows — and scores it like the dense
+    kernel: slab @ W_blk^T in one MXU pass. Sentinel slots alias the
+    all-zero pad block appended after W's last real block and skip the
     contraction under ``pl.when`` — scoring a sparse batch touches
     O(live · C · blk_d) weight lanes instead of O(C · d).
 
@@ -27,7 +28,9 @@ ops.py wrapper with all-zero rows; their score is exactly 0, which can exceed
 a real class's negative score, so the argmax masks lanes ≥ n_classes to -inf
 in-kernel (first-occurrence tie-breaking, matching ``jnp.argmax``). Pad
 convention for the ELL planes is unchanged: (col=0, val=0) entries and
-all-pad rows are inert — a pad query row scores 0 for every class.
+all-pad rows are inert — a pad query row scores 0 for every class. Every
+matmul contracts f32 operands at ``Precision.HIGHEST``, so chip scores agree
+with an f32 ``X @ W^T``.
 Interpret mode off-TPU as everywhere else in this package.
 """
 from __future__ import annotations
@@ -39,8 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-from repro.kernels.hinge_subgrad.sparse import _onehot_gather
+from repro.kernels.hinge_subgrad.sparse import contract_last, onehot_t
 
 __all__ = ["dense_scores", "ell_scores_prefetch"]
 
@@ -64,9 +66,7 @@ def _dense_scores_kernel(x_ref, w_ref, s_ref, l_ref, acc, *, n_classes):
         acc[...] = jnp.zeros_like(acc)
 
     # (blk_b, blk_d) @ (Cp, blk_d)^T — partial scores for this d block
-    acc[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += contract_last(x_ref[...], w_ref[...])
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
@@ -101,14 +101,14 @@ def dense_scores(X: jax.Array, W: jax.Array, *, n_classes: int,
             jax.ShapeDtypeStruct((B,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((blk_b, Cp), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, W)
 
 
 def _ell_scores_prefetch_kernel(bids_ref, cols_ref, vals_ref, w_ref,
-                                s_ref, l_ref, acc, *, blk_d, n_d_blocks,
+                                s_ref, l_ref, acc, slab, *, blk_d, n_d_blocks,
                                 n_classes):
     j = pl.program_id(0)
 
@@ -120,14 +120,14 @@ def _ell_scores_prefetch_kernel(bids_ref, cols_ref, vals_ref, w_ref,
 
     @pl.when(bid < n_d_blocks)  # sentinel slots: DMA aliases the pad block,
     def _():                    # contraction skipped — work tracks live blocks
-        B, k = cols_ref.shape
-        onehot, v = _onehot_gather(cols_ref[...] - bid * blk_d, vals_ref[...],
-                                   blk_d)
-        # (B·k, blk_d) @ (Cp, blk_d)^T: per-entry class rows in one MXU pass
-        gathered = jax.lax.dot_general(
-            onehot, w_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc[...] += jnp.sum((v[:, None] * gathered).reshape(B, k, -1), axis=1)
+        def row(b, carry):
+            # the query row's entries that land in this block, densified
+            oh = onehot_t(cols_ref[pl.ds(b, 1), :], bid * blk_d, blk_d)
+            slab[pl.ds(b, 1), :] = contract_last(vals_ref[pl.ds(b, 1), :], oh)
+            return carry
+
+        jax.lax.fori_loop(0, slab.shape[0], row, 0)
+        acc[...] += contract_last(slab[...], w_ref[...])
 
     @pl.when(j == pl.num_programs(0) - 1)
     def _():
@@ -164,7 +164,8 @@ def ell_scores_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
             pl.BlockSpec((B, Cp), lambda j, b: (0, 0)),
             pl.BlockSpec((B,), lambda j, b: (0,)),
         ],
-        scratch_shapes=[pltpu.VMEM((B, Cp), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((B, Cp), jnp.float32),
+                        pltpu.VMEM((B, blk_d), jnp.float32)],
     )
     return pl.pallas_call(
         kern,
@@ -173,6 +174,6 @@ def ell_scores_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
             jax.ShapeDtypeStruct((B, Cp), jnp.float32),
             jax.ShapeDtypeStruct((B,), jnp.int32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_ids, cols, vals, W)

@@ -10,14 +10,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# f32 contractions on every backend (a TPU's default f32 matmul is one bf16
+# pass); the oracles must not be less exact than the kernels they check.
+F32 = jax.lax.Precision.HIGHEST
+
 
 def half_step_ref(w: jax.Array, X: jax.Array, y: jax.Array, lam: float, t: jax.Array,
                   project: bool = True) -> jax.Array:
     """Oracle for ops.local_half_step: Pegasos half-step, optional projection,
     no loss scalar — the per-node body of GADGET's device-resident loop."""
-    margins = y * (X @ w)
+    margins = y * jnp.matmul(X, w, precision=F32)
     viol = (margins < 1.0).astype(X.dtype)
-    L = (X.T @ (viol * y)) / X.shape[0]
+    L = jnp.matmul(X.T, viol * y, precision=F32) / X.shape[0]
     alpha = 1.0 / (lam * t)
     w_half = (1.0 - lam * alpha) * w + alpha * L
     if project:
@@ -35,9 +39,9 @@ def fleet_half_step_ref(W: jax.Array, X: jax.Array, y: jax.Array, lam: float,
     axis — this is also the fused jnp path GADGET uses where the Pallas
     kernels would only interpret (CPU)."""
     B = X.shape[1]
-    margins = y * jnp.einsum("mbd,md->mb", X, W)
+    margins = y * jnp.einsum("mbd,md->mb", X, W, precision=F32)
     coeff = jnp.where(margins < 1.0, y, 0.0)
-    L = jnp.einsum("mb,mbd->md", coeff, X) / B
+    L = jnp.einsum("mb,mbd->md", coeff, X, precision=F32) / B
     alpha = 1.0 / (lam * t)
     W_half = (1.0 - lam * alpha) * W + alpha * L
     if project:
@@ -99,7 +103,7 @@ def ell_fleet_half_step_ref(W: jax.Array, cols: jax.Array, vals: jax.Array,
 
 def predict_scores_ref(W: jax.Array, X: jax.Array) -> jax.Array:
     """S = X @ W^T. W: (C, d) class weights (C=1 for binary), X: (B, d)."""
-    return X @ W.T
+    return jnp.matmul(X, W.T, precision=F32)
 
 
 def predict_labels_ref(W: jax.Array, X: jax.Array) -> jax.Array:
@@ -113,14 +117,15 @@ def ell_predict_scores_ref(W: jax.Array, cols: jax.Array,
     """Sparse twin: scores for one (B, k) padded-ELL query batch as a
     gather-dot against every class row — S[b, c] = Σ_k vals[b,k]·W[c, cols[b,k]].
     Pad entries (val=0) are inert; an all-pad row scores 0 for every class."""
-    return jnp.einsum("bk,cbk->bc", vals, jnp.take(W, cols, axis=1))
+    return jnp.einsum("bk,cbk->bc", vals, jnp.take(W, cols, axis=1),
+                      precision=F32)
 
 
 def pegasos_step_ref(w: jax.Array, X: jax.Array, y: jax.Array, lam: float, t: jax.Array):
     """Returns (w_new (d,), mean_hinge_loss ()). X: (B, d); y: (B,) in {-1,+1}."""
-    margins = y * (X @ w)
+    margins = y * jnp.matmul(X, w, precision=F32)
     viol = (margins < 1.0).astype(X.dtype)
-    L = (X.T @ (viol * y)) / X.shape[0]
+    L = jnp.matmul(X.T, viol * y, precision=F32) / X.shape[0]
     alpha = 1.0 / (lam * t)
     w_half = (1.0 - lam * alpha) * w + alpha * L
     norm = jnp.linalg.norm(w_half)

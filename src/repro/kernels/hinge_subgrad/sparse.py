@@ -8,21 +8,35 @@ are over weights and never see the ELL planes).
 
 Both kernels run over grid (m, d/blk_d) and express the irregular access as
 an on-the-fly one-hot contraction against the current d-block — the
-MXU-friendly form of gather/scatter on TPU (compare iota, then matmul):
+MXU-friendly form of gather/scatter on TPU (compare iota, then matmul). The
+one-hot is built per minibatch row, transposed: ``onehot_t`` compares the
+row's (1, k) column indices against a (blk_d, k) sublane iota, so the
+planes stay lane-dense in their natural (B, k) layout and no index ever
+moves from lanes to sublanes. Rows are walked by a ``fori_loop`` over the
+real (unpadded) rows only; padded rows are inert.
 
   * ``ell_margins``    — margins m_b = y_b · Σ_k vals[b,k] · w[cols[b,k]].
-    Per d-block: one-hot(cols - block_base) @ w_blk gathers the in-block
-    weight entries (out-of-block indices match no lane and contribute 0 — no
-    explicit mask needed), accumulated over blocks in VMEM scratch.
+    Per d-block and row, w_blk (1, blk_d) @ onehot_t (blk_d, k) gathers the
+    in-block weight entries (out-of-block indices match no sublane and
+    contribute 0 — no explicit mask needed), accumulated over blocks in the
+    resident (B, k) output. Each entry receives exactly one nonzero term, so
+    the gather is exact; the per-row Σ_k vals·w[cols] runs outside the
+    kernel, in the oracle's order.
   * ``ell_grad_update`` — the scatter-add g += Σ_b coeff_b · vals[b,:] onto
     the violator columns, fused with the Pegasos axpy
     w_half = (1 - lam·alpha) w + (alpha/B) g. Each d-block owns its output
     slice, so the grid is embarrassingly parallel — no cross-block scratch.
 
+Layout: w travels as (m, 1, d) rows whose leading axis the grid walks, so
+every block's last two dims are (8, 128) multiples or the array's own
+extents, as Mosaic requires. Both contractions run at
+``Precision.HIGHEST``: the one-hot operand is exact in any precision, and
+f32 weights then come through unrounded on the chip.
+
 Pad convention (repro.sparse.formats.ELL): pad entries carry (col=0, val=0),
 pad *rows* carry y=0 — both are inert in the contraction, so the kernels take
-no validity plane. VMEM per program is the (B·k, blk_d) one-hot plus the
-planes: callers bound B·k·blk_d (ops.ell_fleet_half_step picks blk_d).
+no validity plane. VMEM per program is the planes plus one (blk_d, k)
+one-hot: callers bound k·blk_d (ops.ell_fleet_half_step picks blk_d).
 Interpret mode off-TPU as everywhere else in this package.
 
 Two schedules per op:
@@ -53,80 +67,106 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 __all__ = ["ell_margins", "ell_grad_update", "ell_margins_prefetch",
-           "ell_grad_update_prefetch", "DEFAULT_BLK_D_SPARSE"]
+           "ell_grad_update_prefetch", "onehot_t", "contract_last",
+           "DEFAULT_BLK_D_SPARSE"]
 
 DEFAULT_BLK_D_SPARSE = 512
 
 
-def _onehot_gather(cols, vals, blk_d: int):
-    """(B, k) in-block entry selectors: returns the (B·k, blk_d) one-hot and
-    the flattened (B·k,) values. ``cols`` are already rebased to the block."""
-    Bk = cols.shape[0] * cols.shape[1]
-    local = cols.reshape(Bk, 1)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (Bk, blk_d), 1)
-    onehot = (local == lanes).astype(jnp.float32)  # out-of-block rows: all 0
-    return onehot, vals.reshape(Bk)
+def onehot_t(cols_row, base, blk_d: int):
+    """One row's (1, k) column indices → the (blk_d, k) f32 transposed
+    one-hot of the d-block starting at ``base``: entry e sets sublane
+    ``cols[e] - base`` when that lies in the block, and no sublane
+    otherwise."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (blk_d, cols_row.shape[1]), 0)
+    return ((cols_row - base) == rows).astype(jnp.float32)
 
 
-def _ell_margins_kernel(cols_ref, vals_ref, w_ref, y_ref, m_ref, acc, *, blk_d):
+def contract_last(a, b):
+    """a (r, n) · b (c, n)^T → (r, c), f32 on the MXU at full precision."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _gather_rows(cols_ref, w, base, g_ref, *, blk_d, n_rows):
+    """g[b] += w_blk[cols[b] - base] for the first ``n_rows`` rows."""
+    def row(b, carry):
+        oh = onehot_t(cols_ref[0, pl.ds(b, 1), :], base, blk_d)
+        g_ref[0, pl.ds(b, 1), :] += jnp.dot(
+            w, oh, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
+
+
+def _scatter_rows(cols_ref, contrib_ref, base, *, blk_d, n_rows):
+    """(1, blk_d) Σ_b Σ_k contrib[b, k] over the entries in the d-block."""
+    def row(b, acc):
+        oh = onehot_t(cols_ref[0, pl.ds(b, 1), :], base, blk_d)
+        return acc + contract_last(contrib_ref[0, pl.ds(b, 1), :], oh)
+
+    return jax.lax.fori_loop(0, n_rows, row,
+                             jnp.zeros((1, blk_d), jnp.float32))
+
+
+def _margins_from_gathered(gathered, vals, y):
+    """y · Σ_k vals·w[cols] from the kernel's (m, B, k) gathered weights."""
+    return y * jnp.sum(vals * gathered, axis=-1)
+
+
+def _ell_gather_kernel(cols_ref, w_ref, g_ref, *, blk_d, n_rows):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
-        acc[...] = jnp.zeros_like(acc)
+        g_ref[...] = jnp.zeros_like(g_ref)
 
-    B, k = cols_ref.shape[1], cols_ref.shape[2]
-    onehot, v = _onehot_gather(cols_ref[0] - j * blk_d, vals_ref[0], blk_d)
-    gathered = onehot @ w_ref[0]                      # (B·k,) w[cols] | in-block
-    acc[...] += jnp.sum((v * gathered).reshape(B, k), axis=1)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        m_ref[0] = y_ref[0] * acc[...]
+    _gather_rows(cols_ref, w_ref[0], j * blk_d, g_ref, blk_d=blk_d,
+                 n_rows=n_rows)
 
 
 def ell_margins(cols: jax.Array, vals: jax.Array, W: jax.Array, y: jax.Array, *,
-                blk_d: int = DEFAULT_BLK_D_SPARSE,
+                blk_d: int = DEFAULT_BLK_D_SPARSE, n_rows: int | None = None,
                 interpret: bool = False) -> jax.Array:
     """y * (X @ w) per node over ELL planes. cols/vals: (m, B, k) int32/f32,
-    W: (m, d), y: (m, B) → (m, B) margins. d must be a blk_d multiple."""
+    W: (m, d), y: (m, B) → (m, B) margins. d must be a blk_d multiple;
+    rows past ``n_rows`` (default: all) are padding and score 0."""
     m, B, k = cols.shape
     d = W.shape[1]
     assert d % blk_d == 0, "wrapper must pad d"
-    kern = functools.partial(_ell_margins_kernel, blk_d=blk_d)
-    return pl.pallas_call(
+    kern = functools.partial(_ell_gather_kernel, blk_d=blk_d,
+                             n_rows=B if n_rows is None else n_rows)
+    gathered = pl.pallas_call(
         kern,
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, blk_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, B), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, B), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((B,), jnp.float32)],
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, B, k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cols, vals, W, y)
+    )(cols, W.reshape(m, 1, d))
+    return _margins_from_gathered(gathered, vals, y)
 
 
-def _ell_grad_kernel(cols_ref, vals_ref, w_ref, c_ref, scal_ref, o_ref, *, blk_d):
+def _ell_grad_kernel(cols_ref, contrib_ref, w_ref, scal_ref, o_ref, *, blk_d,
+                     n_rows):
     j = pl.program_id(1)
-    coeff = c_ref[0]                                   # (B,) violator coeffs
-    onehot, v = _onehot_gather(cols_ref[0] - j * blk_d, vals_ref[0], blk_d)
-    contrib = (coeff[:, None] * vals_ref[0]).reshape(v.shape)
-    g = contrib @ onehot                               # (blk_d,) scatter-add
+    g = _scatter_rows(cols_ref, contrib_ref, j * blk_d, blk_d=blk_d,
+                      n_rows=n_rows)
     o_ref[0] = (1.0 - scal_ref[0]) * w_ref[0] + scal_ref[1] * g
 
 
 def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
                     coeff: jax.Array, scal: jax.Array, *,
                     blk_d: int = DEFAULT_BLK_D_SPARSE,
+                    n_rows: int | None = None,
                     interpret: bool = False) -> jax.Array:
     """W_half = (1 - scal[0]) W + scal[1] * scatter(coeff · vals → cols), per
     node. coeff: (m, B) = 1[margin<1]·y; scal: (2,) = [lam·alpha, alpha/B] in
@@ -134,23 +174,24 @@ def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
     m, B, k = cols.shape
     d = W.shape[1]
     assert d % blk_d == 0, "wrapper must pad d"
-    kern = functools.partial(_ell_grad_kernel, blk_d=blk_d)
-    return pl.pallas_call(
+    kern = functools.partial(_ell_grad_kernel, blk_d=blk_d,
+                             n_rows=B if n_rows is None else n_rows)
+    out = pl.pallas_call(
         kern,
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, blk_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, B), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, blk_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cols, vals, W, coeff, scal)
+    )(cols, coeff[:, :, None] * vals, W.reshape(m, 1, d), scal)
+    return out.reshape(m, d)
 
 
 # ---------------------------------------------------------------------------
@@ -158,31 +199,26 @@ def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _ell_margins_prefetch_kernel(bids_ref, cols_ref, vals_ref, w_ref, y_ref,
-                                 m_ref, acc, *, blk_d, n_d_blocks):
+def _ell_gather_prefetch_kernel(bids_ref, cols_ref, w_ref, g_ref, *,
+                                blk_d, n_d_blocks, n_rows):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
-        acc[...] = jnp.zeros_like(acc)
+        g_ref[...] = jnp.zeros_like(g_ref)
 
     bid = bids_ref[i, j]
 
     @pl.when(bid < n_d_blocks)  # sentinel slots: DMA aliases the pad block,
     def _():                    # contraction skipped — FLOPs track live blocks
-        B, k = cols_ref.shape[1], cols_ref.shape[2]
-        onehot, v = _onehot_gather(cols_ref[0] - bid * blk_d, vals_ref[0], blk_d)
-        gathered = onehot @ w_ref[0]
-        acc[...] += jnp.sum((v * gathered).reshape(B, k), axis=1)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        m_ref[0] = y_ref[0] * acc[...]
+        _gather_rows(cols_ref, w_ref[0], bid * blk_d, g_ref, blk_d=blk_d,
+                     n_rows=n_rows)
 
 
 def ell_margins_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
                          y: jax.Array, block_ids: jax.Array, *, blk_d: int,
-                         n_d_blocks: int, interpret: bool = False) -> jax.Array:
+                         n_d_blocks: int, n_rows: int | None = None,
+                         interpret: bool = False) -> jax.Array:
     """Touched-block twin of :func:`ell_margins`.
 
     ``block_ids``: (m, n_blocks_max) compact touched-block-id map (live ids
@@ -191,48 +227,48 @@ def ell_margins_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
     against. W must carry the sentinel's landing pad: shape
     (m, (n_d_blocks + 1)·blk_d) with the last block all-zero."""
     m, B, k = cols.shape
-    assert W.shape[1] == (n_d_blocks + 1) * blk_d, "caller pads W + zero block"
+    d = W.shape[1]
+    assert d == (n_d_blocks + 1) * blk_d, "caller pads W + zero block"
     n_blocks_max = block_ids.shape[1]
-    kern = functools.partial(_ell_margins_prefetch_kernel, blk_d=blk_d,
-                             n_d_blocks=n_d_blocks)
+    kern = functools.partial(_ell_gather_prefetch_kernel, blk_d=blk_d,
+                             n_d_blocks=n_d_blocks,
+                             n_rows=B if n_rows is None else n_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m, n_blocks_max),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
-            pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
-            pl.BlockSpec((1, blk_d), lambda i, j, b: (i, b[i, j])),
-            pl.BlockSpec((1, B), lambda i, j, b: (i, 0)),
+            pl.BlockSpec((1, 1, blk_d), lambda i, j, b: (i, 0, b[i, j])),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i, j, b: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((B,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    gathered = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, B), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((m, B, k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_ids, cols, vals, W, y)
+    )(block_ids, cols, W.reshape(m, 1, d))
+    return _margins_from_gathered(gathered, vals, y)
 
 
-def _ell_grad_prefetch_kernel(bids_ref, cols_ref, vals_ref, c_ref, g_ref, *,
-                              blk_d, n_d_blocks):
+def _ell_grad_prefetch_kernel(bids_ref, cols_ref, contrib_ref, g_ref, *,
+                              blk_d, n_d_blocks, n_rows):
     i, j = pl.program_id(0), pl.program_id(1)
     bid = bids_ref[i, j]
     g_ref[0, 0] = jnp.zeros_like(g_ref[0, 0])
 
     @pl.when(bid < n_d_blocks)
     def _():
-        onehot, v = _onehot_gather(cols_ref[0] - bid * blk_d, vals_ref[0], blk_d)
-        contrib = (c_ref[0][:, None] * vals_ref[0]).reshape(v.shape)
-        g_ref[0, 0] = contrib @ onehot
+        g_ref[0, 0] = _scatter_rows(cols_ref, contrib_ref, bid * blk_d,
+                                    blk_d=blk_d, n_rows=n_rows)
 
 
 def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
                              coeff: jax.Array, block_ids: jax.Array, *,
                              blk_d: int, n_d_blocks: int,
+                             n_rows: int | None = None,
                              interpret: bool = False) -> jax.Array:
     """Touched-block twin of :func:`ell_grad_update`'s scatter phase.
 
@@ -245,22 +281,23 @@ def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
     m, B, k = cols.shape
     n_blocks_max = block_ids.shape[1]
     kern = functools.partial(_ell_grad_prefetch_kernel, blk_d=blk_d,
-                             n_d_blocks=n_d_blocks)
+                             n_d_blocks=n_d_blocks,
+                             n_rows=B if n_rows is None else n_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m, n_blocks_max),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
             pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
-            pl.BlockSpec((1, B), lambda i, j, b: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, blk_d), lambda i, j, b: (i, j, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, blk_d), lambda i, j, b: (i, j, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n_blocks_max, blk_d), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((m, n_blocks_max, 1, blk_d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_ids, cols, vals, coeff)
+    )(block_ids, cols, coeff[:, :, None] * vals)
+    return out.reshape(m, n_blocks_max, blk_d)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["rglru_scan"]
 
@@ -58,7 +57,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, blk_s: int = 256, blk_d: int = 256
         out_specs=pl.BlockSpec((1, blk_s, blk_d), lambda bb, dd, ss: (bb, ss, dd)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((blk_d,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["wkv_scan"]
 
@@ -67,7 +66,7 @@ def wkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, n), r.dtype),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

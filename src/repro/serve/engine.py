@@ -462,11 +462,10 @@ def make_mesh_scorer(W, *, mesh=None, axis: str = "batch",
     sharded over ``mesh``'s ``axis`` (defaults to a 1-D mesh over every local
     device) and the class weights are closed over — replicated to each shard,
     never gathered. B must divide by the axis size (pad with zero rows; they
-    score 0 and slice away). ``check_rep=False`` for the kernel path — jax
+    score 0 and slice away). ``check_vma=False`` for the kernel path — jax
     has no ``pallas_call`` replication rule inside ``shard_map`` yet, same
     pin as the training mesh step."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (axis,))
@@ -485,6 +484,6 @@ def make_mesh_scorer(W, *, mesh=None, axis: str = "batch",
         return hinge_ops._finish_predict(scores, labels, Xl.shape[0],
                                          1 if binary else W_dev.shape[0], binary)
 
-    sharded = shard_map(per_shard, mesh=mesh, in_specs=P(axis),
-                        out_specs=(P(axis), P(axis)), check_rep=False)
+    sharded = jax.shard_map(per_shard, mesh=mesh, in_specs=P(axis),
+                            out_specs=(P(axis), P(axis)), check_vma=False)
     return jax.jit(sharded)

@@ -185,7 +185,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core.faults import FaultPlan
 from repro.core.gadget import GadgetConfig, make_gadget_mesh_step
 
@@ -201,8 +200,8 @@ def runner(step):
     def per_node(w, x, yl, keys, t):
         return step(w[0], x[0], yl[0], t, keys[0])[None]
     specs = (P("nodes"),) * 4 + (P(),)
-    return jax.jit(shard_map(per_node, mesh=mesh, in_specs=specs,
-                             out_specs=P("nodes"), check_rep=False))
+    return jax.jit(jax.shard_map(per_node, mesh=mesh, in_specs=specs,
+                                 out_specs=P("nodes"), check_vma=False))
 
 def train(step, iters=6):
     W = jnp.zeros((m, d), jnp.float32)

@@ -393,6 +393,25 @@ class TestSparseDatasets:
             row_nnz = (np.asarray(ds.X_train) != 0).sum(axis=1)
             assert np.all(row_nnz == nnz_target), (name, row_nnz[:5], nnz_target)
 
+    def test_zipf_race_matches_plain_exponential_race(self):
+        """The thread-pooled, in-place skewed sampler picks the same columns
+        as the plain race over one sequential ``rng.random`` draw (keys
+        log(U)·(r+1)^skew, the nnz largest per row), and leaves the stream
+        where that draw would (later draws — values, labels — unchanged)."""
+        n, d, nnz, skew = 5000, 4096, 40, 1.25    # three 2048-row chunks
+        rng = np.random.default_rng(5)
+        rng.normal(size=3)
+        seq = np.random.default_rng(5)
+        seq.normal(size=3)
+        got = svm_datasets._zipf_race(rng, n, nnz, d, skew)
+        with np.errstate(divide="ignore"):
+            keys = np.log(seq.random((n, d), dtype=np.float32))
+        keys *= np.arange(1, d + 1, dtype=np.float32) ** np.float32(skew)
+        want = np.argpartition(keys, d - nnz, axis=1)[:, d - nnz:]
+        np.testing.assert_array_equal(np.sort(got, axis=1),
+                                      np.sort(want, axis=1))
+        np.testing.assert_array_equal(rng.random(7), seq.random(7))
+
     def test_sparse_dataset_emits_ell(self):
         spec = svm_datasets.PAPER_DATASETS["reuters"]
         ds = svm_datasets.make_dataset("reuters", scale=0.02, seed=0, sparse=True)
@@ -514,7 +533,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core.gadget import GadgetConfig, make_gadget_mesh_step
 from repro.data import svm_datasets
 
@@ -534,9 +552,9 @@ def sharded(step, sparse):
         X_local = (c[0], v[0]) if sparse else x[0]
         return step(w[0], X_local, y[0], t, keys[0])[None]
     specs = (P("nodes"),) * 6 + (P(),)
-    # check_rep=False: no replication rule for pallas_call in shard_map yet
-    return shard_map(per_node, mesh=mesh, in_specs=specs, out_specs=P("nodes"),
-                     check_rep=False)
+    # check_vma=False: no replication rule for pallas_call in shard_map yet
+    return jax.shard_map(per_node, mesh=mesh, in_specs=specs,
+                         out_specs=P("nodes"), check_vma=False)
 
 cols, vals = jnp.asarray(Pe.cols), jnp.asarray(Pe.vals)
 Xd, yj = jnp.asarray(Xd), jnp.asarray(yp)
@@ -576,7 +594,6 @@ class TestMeshSparse:
         kernels inside shard_map without a mesh-collective in sight."""
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core.gadget import make_gadget_mesh_step
 
         ds = svm_datasets.make_dataset("reuters", scale=0.02, seed=0, sparse=True)
@@ -590,9 +607,10 @@ class TestMeshSparse:
         y0 = jnp.asarray(yp[0])
         w0 = jnp.zeros((Pe.d,), jnp.float32)
         key = jax.random.PRNGKey(7)
-        f = shard_map(lambda w, c, v, y, k: step(w, (c, v), y, jnp.int32(1), k),
-                      mesh=mesh, in_specs=(P(), P(), P(), P(), P()),
-                      out_specs=P(), check_rep=False)
+        f = jax.shard_map(
+            lambda w, c, v, y, k: step(w, (c, v), y, jnp.int32(1), k),
+            mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P(),
+            check_vma=False)
         got = jax.jit(f)(w0, cols, vals, y0, key)
         want = step(w0, (cols, vals), y0, jnp.int32(1), key)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
