@@ -62,7 +62,6 @@ and the objective trace all respect them.
 from __future__ import annotations
 
 import functools
-import time
 from typing import NamedTuple
 
 import jax
@@ -262,7 +261,9 @@ class TrainState(NamedTuple):
 
 # Host↔device traffic instrumentation, read by benchmarks/gossip_device_bench.py:
 # `matrix_uploads` counts host→device transfers of mixing matrices, `host_syncs`
-# counts device→host scalar pulls made for the anytime ε-check / traces.
+# counts blocking boundaries (the host waits for the device), not transfers:
+# the device→host reads made at a stream's segment boundary are counted by the
+# registry counter `train.host_readbacks`.
 transfer_stats = {"matrix_uploads": 0, "host_syncs": 0}
 
 
@@ -388,28 +389,29 @@ def _iter_mixing(mix_key: jax.Array, B_stack: jax.Array | None, t: jax.Array,
     def zero_drops():
         return (jnp.zeros((m,), jnp.int32) if drops_node else jnp.int32(0))
 
-    if topology == "random":
-        kt = jax.random.fold_in(mix_key, t)
-        Bs = jax.vmap(
-            lambda r: topo.random_neighbor_matrix_device(jax.random.fold_in(kt, r), m)
-        )(jnp.arange(R))
-    else:
-        T = B_stack.shape[0]
-        if fused and faults is None:
-            P = B_stack[(t - 1) % T]
-            return (P, zero_drops()) if count_drops else P
-        idx = ((t - 1) * R + jnp.arange(R)) % T
-        Bs = B_stack[idx]
-    drops = None
-    if faults is not None:
+    with jax.named_scope("gadget.push_sum_mix"):
+        if topology == "random":
+            kt = jax.random.fold_in(mix_key, t)
+            Bs = jax.vmap(
+                lambda r: topo.random_neighbor_matrix_device(jax.random.fold_in(kt, r), m)
+            )(jnp.arange(R))
+        else:
+            T = B_stack.shape[0]
+            if fused and faults is None:
+                P = B_stack[(t - 1) % T]
+                return (P, zero_drops()) if count_drops else P
+            idx = ((t - 1) * R + jnp.arange(R)) % T
+            Bs = B_stack[idx]
+        drops = None
+        if faults is not None:
+            if count_drops:
+                drops = (flt.count_drops_node(Bs, faults, t) if drops_node
+                         else flt.count_drops(Bs, faults, t))
+            Bs = flt.faulty_rounds(Bs, faults, t)
+        mix = collapse_rounds(Bs) if fused else Bs
         if count_drops:
-            drops = (flt.count_drops_node(Bs, faults, t) if drops_node
-                     else flt.count_drops(Bs, faults, t))
-        Bs = flt.faulty_rounds(Bs, faults, t)
-    mix = collapse_rounds(Bs) if fused else Bs
-    if count_drops:
-        return mix, (zero_drops() if drops is None else drops)
-    return mix
+            return mix, (zero_drops() if drops is None else drops)
+        return mix
 
 
 # ---------------------------------------------------------------------------
@@ -443,6 +445,38 @@ def _gossip_step(cfg: GadgetConfig, m: int,
     mass ratio ``wts_i / n_i`` — the node-level decomposition of ``mass``
     (its n-weighted mean is the scalar) — as a fourth output; the default
     three-output form traces the identical program."""
+    with jax.named_scope("gadget.half_step"):
+        W_half = _fleet_half_step(cfg, X, y, n_counts, data_key, W, t,
+                                  sparse_block_bound)
+    # Push-Sum: values n_i·w̃_i with mass weights n_i ⇒ weighted mean; R
+    # rounds collapsed into one fused mix-and-renormalize matmul when fused.
+    with jax.named_scope("gadget.push_sum_mix"):
+        mix = mix_collapsed if cfg.fused else mix_rounds
+        vals, wts = mix(W_half * n_counts[:, None], n_counts, Bs)
+        mass = jnp.sum(wts) / jnp.sum(n_counts)
+        W_new = vals / wts[:, None]
+    if cfg.project_after_gossip:
+        with jax.named_scope("gadget.project"):
+            W_new = jax.vmap(lambda w: obj.project_ball(w, cfg.lam))(W_new)
+    if cfg.faults is not None and cfg.faults.dead_nodes:
+        # crashed nodes neither train nor receive: their mixing row is e_d
+        # (nothing reaches the others), and the bit-exact freeze of their own
+        # row happens here, after the mix's renormalizing divide
+        with jax.named_scope("gadget.push_sum_mix"):
+            W_new = jnp.where(flt.dead_mask(cfg.faults, m)[:, None], W, W_new)
+    with jax.named_scope("gadget.average"):
+        W_sum = W_sum + W_new
+    if node_mass:
+        return W_new, W_sum, mass, wts / n_counts
+    return W_new, W_sum, mass
+
+
+def _fleet_half_step(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
+                     data_key: jax.Array, W: jax.Array, t: jax.Array,
+                     sparse_block_bound: int | None):
+    """Steps (a)-(f) for all m nodes: each node's minibatch draw and gather,
+    then the Pallas or jnp half-step, whose ball projection (step (f)) runs
+    under the ``gadget.project`` scope."""
     tf = t.astype(jnp.float32)
     ids = _batch_ids(data_key, t, n_counts, cfg.batch_size)
 
@@ -477,22 +511,7 @@ def _gossip_step(cfg: GadgetConfig, m: int,
             lambda w, Xi, yi, ii: _local_half_step(w, Xi, yi, ii, cfg.lam, tf,
                                                    cfg.project_before_gossip, cfg.use_kernels)
         )(W, X, y, ids)
-    # Push-Sum: values n_i·w̃_i with mass weights n_i ⇒ weighted mean; R
-    # rounds collapsed into one fused mix-and-renormalize matmul when fused.
-    mix = mix_collapsed if cfg.fused else mix_rounds
-    vals, wts = mix(W_half * n_counts[:, None], n_counts, Bs)
-    mass = jnp.sum(wts) / jnp.sum(n_counts)
-    W_new = vals / wts[:, None]
-    if cfg.project_after_gossip:
-        W_new = jax.vmap(lambda w: obj.project_ball(w, cfg.lam))(W_new)
-    if cfg.faults is not None and cfg.faults.dead_nodes:
-        # crashed nodes neither train nor receive: their mixing row is e_d
-        # (nothing reaches the others), and the bit-exact freeze of their own
-        # row happens here, after the mix's renormalizing divide
-        W_new = jnp.where(flt.dead_mask(cfg.faults, m)[:, None], W, W_new)
-    if node_mass:
-        return W_new, W_sum + W_new, mass, wts / n_counts
-    return W_new, W_sum + W_new, mass
+    return W_half
 
 
 def _one_iteration(cfg: GadgetConfig, m: int,
@@ -545,27 +564,32 @@ def _trace_closures(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
     (data-weighted network average). Built identically by the while-loop
     trainer, the segment trainer and the host reference so their traces agree
     bit-for-bit."""
-    y_flat = y.reshape(m * n_i)
     total_n = jnp.sum(n_counts)
-    valid_flat = _valid_row_mask(m, n_i, n_counts)
-    if isinstance(X, tuple):  # ELL planes: full-data pass as a gather-dot
-        cols_flat = X[0].reshape(m * n_i, -1)
-        vals_flat = X[1].reshape(m * n_i, -1)
+    with jax.named_scope("gadget.objective"):  # the full-data pass's flat views
+        y_flat = y.reshape(m * n_i)
+        valid_flat = _valid_row_mask(m, n_i, n_counts)
+        if isinstance(X, tuple):  # ELL planes: the pass is a gather-dot
+            flat = (X[0].reshape(m * n_i, -1), X[1].reshape(m * n_i, -1))
+            primal = obj.primal_objective_masked_ell
+        else:
+            flat = (X.reshape(m * n_i, d),)
+            primal = obj.primal_objective_masked
 
-        def objective_of(w):
-            return obj.primal_objective_masked_ell(
-                w, cols_flat, vals_flat, y_flat, cfg.lam, valid_flat, total_n)
-    else:
-        X_flat = X.reshape(m * n_i, d)
-
-        def objective_of(w):
-            return obj.primal_objective_masked(
-                w, X_flat, y_flat, cfg.lam, valid_flat, total_n)
+    def objective_of(w):
+        with jax.named_scope("gadget.objective"):
+            return primal(w, *flat, y_flat, cfg.lam, valid_flat, total_n)
 
     def consensus_of(W):
-        return jnp.sum(W * n_counts[:, None], axis=0) / total_n
+        with jax.named_scope("gadget.consensus"):
+            return jnp.sum(W * n_counts[:, None], axis=0) / total_n
 
     return objective_of, consensus_of
+
+
+def _eps_check(W: jax.Array, W_prev: jax.Array) -> jax.Array:
+    """The stopping rule's ε = max_i ‖W_i − W_prev_i‖ over one check window."""
+    with jax.named_scope("gadget.eps_check"):
+        return jnp.max(jnp.linalg.norm(W - W_prev, axis=1))
 
 
 def _cache_cfg(cfg: GadgetConfig) -> GadgetConfig:
@@ -738,7 +762,7 @@ def _make_device_train(cfg: GadgetConfig, m: int, n_i: int, d: int,
             W_prev = W
             (W, W_sum, t, snaps, tele), masses = jax.lax.scan(
                 step, (W, W_sum, t, snaps, tele), None, length=chunk)
-            eps = jnp.max(jnp.linalg.norm(W - W_prev, axis=1))
+            eps = _eps_check(W, W_prev)
             w_cons = consensus_of(W)
             obj_tr = obj_tr.at[ci].set(objective_of(w_cons))
             it_tr = it_tr.at[ci].set(t - 1)
@@ -1140,7 +1164,7 @@ def _make_segment_train(cfg: GadgetConfig, m: int, n_i: int, d: int,
         (W, W_sum, t), ys = jax.lax.scan(step, (W, W_sum, t0), None,
                                          length=seg_len)
         masses, drops = ys if tele else (ys, None)
-        eps = jnp.max(jnp.linalg.norm(W - W_prev, axis=1))
+        eps = _eps_check(W, W_prev)
         w_cons = consensus_of(W)
         base = (W, W_sum, t, w_cons, objective_of(w_cons), eps,
                 jnp.min(masses))
@@ -1192,7 +1216,10 @@ def gadget_train_stream(
     the segment where ``ε < cfg.epsilon`` or ``cfg.max_iters`` is reached
     (that last result carries ``done=True``). Accepts the same dense
     (m, n_i, d) / ``EllPartitions`` data and ``n_counts`` conventions as
-    ``gadget_train``. One host sync per segment, by construction.
+    ``gadget_train``. The host blocks once per segment, at its boundary, then
+    reads the segment's outputs back one array at a time (five reads, nine
+    with ``telemetry``), each counted on the registry counter
+    ``train.host_readbacks``.
 
     ``resume`` (optional :class:`TrainState`, e.g. from
     ``repro.serve.snapshot.train_state_from_checkpoint``): continue a
@@ -1210,10 +1237,17 @@ def gadget_train_stream(
     the segment boundary IS the cadence). ``telemetry=None`` (default)
     traces the exact pre-telemetry program: trajectories stay bit-identical.
 
+    Every segment is one ``train.segment`` span on ``trace_registry``
+    (default: the process default registry) — segment wall seconds,
+    iteration, objective, ε — holding the child spans
+    ``train.segment.dispatch`` (the jitted call), ``train.segment.wait``
+    (``block_until_ready``), one ``train.readback`` per read (field ``what``)
+    and ``train.segment.account`` (finite check, telemetry, gauges). Each is
+    also a profiler annotation, so a profiler trace shows the boundary on the
+    device operations' clock.
+
     ``trace=True`` starts one causal trace per segment (the version-lineage
-    root): a ``train.segment`` span — segment wall seconds, iteration,
-    objective — is emitted on ``trace_registry`` (default: the process
-    default registry) at every boundary, and
+    root): the ``train.segment`` span and its children carry its ids, and
     the root :class:`~repro.telemetry.trace.TraceContext` rides out on
     ``SegmentResult.trace`` for the publisher to extend (explicit
     propagation across the thread boundary; host-side only, the traced
@@ -1264,64 +1298,83 @@ def gadget_train_stream(
         W = jnp.zeros((m, d), dtype)
         W_sum = jnp.zeros((m, d), dtype)
         t = jnp.int32(1)
+    reg = trace_registry if trace_registry is not None else tmr.default_registry()
+    iteration = int(resume.iteration) if resume is not None else 0
     first_segment = True
     while True:
-        prev_iteration = int(t) - 1
-        seg_t0 = time.monotonic()
-        out = segment(X, y, B_stack, data_key, mix_key, n_counts, W, W_sum, t)
-        out = jax.block_until_ready(out)
-        seg_seconds = time.monotonic() - seg_t0
-        seg_tele = None
-        if tele_cfg:
-            (W, W_sum, t, w_cons, objective, eps, mass,
-             dis, seg_mn, seg_mx, seg_drops) = out
-            seg_tele = tmt.SegmentTelemetry(
-                disagreement=float(dis), mass_min=float(seg_mn),
-                mass_max=float(seg_mx), objective=float(objective),
-                drops=int(seg_drops))
-        else:
-            W, W_sum, t, w_cons, objective, eps, mass = out
-        transfer_stats["host_syncs"] += 1  # one sync per segment boundary
-        iteration = int(t) - 1
-        if not np.all(np.isfinite(np.asarray(w_cons))):
-            # segment boundaries ARE the stream's check cadence and the
-            # consensus is already host-synced here, so the guard is a free
-            # host-side reduction — same typed failure as the device loop,
-            # and it fires before a publisher could flush the segment
-            tmr.default_registry().counter("train.nonfinite").inc()
-            raise NonFiniteWeightsError(iteration)
-        _record_train_telemetry(cfg, m, d, X, sparse_block_bound,
-                                iteration - prev_iteration)
-        if seg_tele is not None:
-            reg = tmr.default_registry()
-            reg.gauge("train.final_disagreement").set(seg_tele.disagreement)
-            reg.gauge("train.objective").set(seg_tele.objective)
-            if np.isfinite(seg_tele.mass_min):
-                reg.gauge("train.mass_min").set(seg_tele.mass_min)
-                reg.gauge("train.mass_max").set(seg_tele.mass_max)
-            reg.counter("train.fault_drops").inc(seg_tele.drops)
-        eps_f = float(eps)
-        done = eps_f < cfg.epsilon or iteration >= cfg.max_iters
-        seg_ctx = None
-        if trace:
-            # one fresh trace per segment: this span is the lineage root the
-            # publisher/server chain hangs off (via SegmentResult.trace)
-            seg_ctx = tmtr.TraceContext.new()
-            attrs = {"iteration": iteration, "objective": float(objective),
-                     "epsilon": eps_f, "done": done}
-            if first_segment and trace_link:
-                attrs["resumed_from_trace"] = trace_link
-            tmtr.emit_span(trace_registry if trace_registry is not None
-                           else tmr.default_registry(),
-                           "train.segment", seg_ctx, seg_seconds, **attrs)
+        prev_iteration = iteration
+        # one fresh trace per segment: this span is the lineage root the
+        # publisher/server chain hangs off (via SegmentResult.trace)
+        seg_ctx = tmtr.TraceContext.new() if trace else None
+        with reg.span("train.segment", ctx=seg_ctx) as seg_span:
+            with reg.span("train.segment.dispatch", ctx=_child(seg_ctx)):
+                out = segment(X, y, B_stack, data_key, mix_key, n_counts, W, W_sum, t)
+            with reg.span("train.segment.wait", ctx=_child(seg_ctx)):
+                out = jax.block_until_ready(out)
+            transfer_stats["host_syncs"] += 1  # one blocking boundary per segment
+            if tele_cfg:
+                (W, W_sum, t, w_cons, objective, eps, mass,
+                 dis, seg_mn, seg_mx, seg_drops) = out
+            else:
+                W, W_sum, t, w_cons, objective, eps, mass = out
+            iteration = _readback(reg, seg_ctx, "t", t, int) - 1
+            w_host = _readback(reg, seg_ctx, "w_consensus", w_cons, np.asarray)
+            eps_f = _readback(reg, seg_ctx, "epsilon", eps, float)
+            objective_f = _readback(reg, seg_ctx, "objective", objective, float)
+            mass_f = _readback(reg, seg_ctx, "mass", mass, float)
+            seg_tele = None
+            if tele_cfg:
+                seg_tele = tmt.SegmentTelemetry(
+                    disagreement=_readback(reg, seg_ctx, "disagreement", dis, float),
+                    mass_min=_readback(reg, seg_ctx, "mass_min", seg_mn, float),
+                    mass_max=_readback(reg, seg_ctx, "mass_max", seg_mx, float),
+                    objective=objective_f,
+                    drops=_readback(reg, seg_ctx, "drops", seg_drops, int))
+            with reg.span("train.segment.account", ctx=_child(seg_ctx)):
+                if not np.all(np.isfinite(w_host)):
+                    # segment boundaries ARE the stream's check cadence and
+                    # the consensus is already on the host here, so the guard
+                    # is a free host-side reduction — same typed failure as
+                    # the device loop, and it fires before a publisher could
+                    # flush the segment
+                    tmr.default_registry().counter("train.nonfinite").inc()
+                    raise NonFiniteWeightsError(iteration)
+                _record_train_telemetry(cfg, m, d, X, sparse_block_bound,
+                                        iteration - prev_iteration)
+                if seg_tele is not None:
+                    greg = tmr.default_registry()
+                    greg.gauge("train.final_disagreement").set(seg_tele.disagreement)
+                    greg.gauge("train.objective").set(seg_tele.objective)
+                    if np.isfinite(seg_tele.mass_min):
+                        greg.gauge("train.mass_min").set(seg_tele.mass_min)
+                        greg.gauge("train.mass_max").set(seg_tele.mass_max)
+                    greg.counter("train.fault_drops").inc(seg_tele.drops)
+                done = eps_f < cfg.epsilon or iteration >= cfg.max_iters
+            seg_span.fields.update(
+                iteration=iteration, objective=objective_f, epsilon=eps_f,
+                done=done,
+                resumed_from_trace=trace_link if first_segment else None)
         first_segment = False
-        yield SegmentResult(iteration=iteration, W=W,
-                            w_consensus=np.asarray(w_cons),
-                            objective=float(objective), epsilon=eps_f,
-                            done=done, W_sum=W_sum, mass=float(mass),
+        yield SegmentResult(iteration=iteration, W=W, w_consensus=w_host,
+                            objective=objective_f, epsilon=eps_f,
+                            done=done, W_sum=W_sum, mass=mass_f,
                             telemetry=seg_tele, trace=seg_ctx)
         if done:
             return
+
+
+def _child(ctx: tmtr.TraceContext | None) -> tmtr.TraceContext | None:
+    return None if ctx is None else ctx.child()
+
+
+def _readback(reg, seg_ctx, what: str, x: jax.Array, convert):
+    """``convert(x)``: one device→host read at a segment boundary, timed in a
+    ``train.readback`` span (field ``what``) and counted on the registry
+    counter ``train.host_readbacks``. Called once per array and segment: a
+    later read of the same array hits JAX's host copy and transfers nothing."""
+    with reg.span("train.readback", ctx=_child(seg_ctx), what=what):
+        reg.counter("train.host_readbacks").inc()
+        return convert(x)
 
 
 # ---------------------------------------------------------------------------

@@ -69,6 +69,7 @@ def margins(X: jax.Array, w: jax.Array, y: jax.Array, *,
     assert B % blk_b == 0 and d % blk_d == 0, "wrapper must pad"
     out = pl.pallas_call(
         _margins_kernel,
+        name="local_half_step_margins",
         grid=(B // blk_b, d // blk_d),
         in_specs=[
             pl.BlockSpec((blk_b, blk_d), lambda i, j: (i, j)),
@@ -113,6 +114,7 @@ def fleet_half_step(X: jax.Array, W: jax.Array, y: jax.Array,
     m, B, d = X.shape
     out = pl.pallas_call(
         _fleet_kernel,
+        name="fleet_half_step",
         grid=(m,),
         in_specs=[
             pl.BlockSpec((1, B, d), lambda i: (i, 0, 0)),
@@ -159,6 +161,7 @@ def grad_update(X: jax.Array, w: jax.Array, coeff: jax.Array, scal: jax.Array, *
     assert B % blk_b == 0 and d % blk_d == 0, "wrapper must pad"
     out = pl.pallas_call(
         _update_kernel,
+        name="local_half_step_update",
         grid=(d // blk_d, B // blk_b),
         in_specs=[
             pl.BlockSpec((blk_b, blk_d), lambda i, j: (j, i)),

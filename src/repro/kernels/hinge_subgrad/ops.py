@@ -197,7 +197,8 @@ def local_half_step(w: jax.Array, X: jax.Array, y: jax.Array, *, lam: float,
     w_half = K.grad_update(Xp, wp, coeff, scal, blk_b=blk_b_, blk_d=blk_d_,
                            interpret=interpret)[:d]
     if project:
-        w_half = _project_ball(w_half, lam)
+        with jax.named_scope("gadget.project"):
+            w_half = _project_ball(w_half, lam)
     return w_half.astype(w.dtype)
 
 
@@ -239,7 +240,8 @@ def fleet_half_step(W: jax.Array, X: jax.Array, y: jax.Array, *, lam: float,
     scal = jnp.stack([lam * alpha, alpha / B])
     W_half = K.fleet_half_step(Xp, Wp, yp, mask, scal, interpret=interpret)[:, :d]
     if project:
-        W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
+        with jax.named_scope("gadget.project"):
+            W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
     return W_half.astype(W.dtype)
 
 
@@ -413,7 +415,8 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         W_half = S.ell_grad_update(colsP, valsP, Wp, coeff, scal, blk_d=blk_d,
                                    n_rows=B, interpret=interpret)[:, :d]
     if project:
-        W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
+        with jax.named_scope("gadget.project"):
+            W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
     return W_half.astype(W.dtype)
 
 

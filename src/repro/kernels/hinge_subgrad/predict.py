@@ -87,6 +87,7 @@ def dense_scores(X: jax.Array, W: jax.Array, *, n_classes: int,
     kern = functools.partial(_dense_scores_kernel, n_classes=n_classes)
     return pl.pallas_call(
         kern,
+        name="dense_predict",
         grid=(B // blk_b, d // blk_d),
         in_specs=[
             pl.BlockSpec((blk_b, blk_d), lambda i, j: (i, j)),
@@ -169,6 +170,7 @@ def ell_scores_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
     )
     return pl.pallas_call(
         kern,
+        name="ell_predict",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Cp), jnp.float32),

@@ -25,9 +25,10 @@ def half_step_ref(w: jax.Array, X: jax.Array, y: jax.Array, lam: float, t: jax.A
     alpha = 1.0 / (lam * t)
     w_half = (1.0 - lam * alpha) * w + alpha * L
     if project:
-        norm = jnp.linalg.norm(w_half)
-        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norm, 1e-30))
-        w_half = w_half * scale
+        with jax.named_scope("gadget.project"):
+            norm = jnp.linalg.norm(w_half)
+            scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norm, 1e-30))
+            w_half = w_half * scale
     return w_half
 
 
@@ -45,9 +46,10 @@ def fleet_half_step_ref(W: jax.Array, X: jax.Array, y: jax.Array, lam: float,
     alpha = 1.0 / (lam * t)
     W_half = (1.0 - lam * alpha) * W + alpha * L
     if project:
-        norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
-        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
-        W_half = W_half * scale
+        with jax.named_scope("gadget.project"):
+            norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
+            scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
+            W_half = W_half * scale
     return W_half
 
 
@@ -90,9 +92,10 @@ def ell_fleet_half_step_ref(W: jax.Array, cols: jax.Array, vals: jax.Array,
     alpha = 1.0 / (lam * t)
     W_half = (1.0 - lam * alpha) * W + alpha * L
     if project:
-        norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
-        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
-        W_half = W_half * scale
+        with jax.named_scope("gadget.project"):
+            norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
+            scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
+            W_half = W_half * scale
     return W_half
 
 

@@ -141,6 +141,7 @@ def ell_margins(cols: jax.Array, vals: jax.Array, W: jax.Array, y: jax.Array, *,
                              n_rows=B if n_rows is None else n_rows)
     gathered = pl.pallas_call(
         kern,
+        name="ell_fleet_half_step_sweep_gather",
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
@@ -178,6 +179,7 @@ def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
                              n_rows=B if n_rows is None else n_rows)
     out = pl.pallas_call(
         kern,
+        name="ell_fleet_half_step_sweep_update",
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
@@ -244,6 +246,7 @@ def ell_margins_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
     )
     gathered = pl.pallas_call(
         kern,
+        name="ell_fleet_half_step_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, B, k), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -294,6 +297,7 @@ def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
     )
     out = pl.pallas_call(
         kern,
+        name="ell_fleet_half_step_update",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_blocks_max, 1, blk_d), jnp.float32),
         compiler_params=pltpu.CompilerParams(

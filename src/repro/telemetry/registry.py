@@ -28,6 +28,7 @@ Export lives in :mod:`repro.telemetry.export` (Prometheus text + JSONL);
 from __future__ import annotations
 
 import math
+import sys
 import threading
 import time
 
@@ -42,6 +43,8 @@ __all__ = [
     "gauge",
     "histogram",
     "span",
+    "span_record",
+    "trace_ids",
     "reset",
 ]
 
@@ -262,37 +265,76 @@ class Histogram:
 
 
 class Span:
-    """Context manager timing one host-side phase into a histogram.
+    """Context manager timing one host-side phase: the package's one span.
 
     ``with registry.span("publisher.publish_seconds", step=40): ...``
     observes the wall-clock duration into the histogram named ``name`` (one
     series per name) and, when the registry has a JSONL sink attached, emits
-    a ``span`` event carrying ``fields`` (e.g. the step number) and the
-    measured seconds.
+    a ``span`` record carrying ``fields`` (e.g. the step number) and the
+    measured seconds. With ``ctx`` (a :class:`~repro.telemetry.trace.
+    TraceContext`, its place in a causal trace) the record also carries
+    ``trace_id`` / ``span_id`` / ``parent_id`` at the top level;
+    :func:`repro.telemetry.trace.TracedSpan` builds such a span. Fields set to
+    ``None`` are left out of the record, and ``fields`` may be filled in
+    while the span is open.
+
+    While open, the span is also a ``jax.profiler.TraceAnnotation`` of its
+    name (with the fields given at entry as the event's stats), so under an
+    active profiler it lands in the ``.xplane.pb`` on the device operations'
+    clock; without one it costs one inactive-TraceMe check. In a process that
+    has not imported jax there is no profiler to write to, and no annotation.
 
     Spans close on the exception path too: a raise inside the block still
     observes the histogram and emits the record, with an ``error`` field
     naming the exception (the raise itself propagates unchanged).
     """
 
-    def __init__(self, registry: "Registry", name: str, fields: dict):
+    def __init__(self, registry: "Registry", name: str, fields: dict,
+                 ctx=None):
         self.registry = registry
         self.name = name
         self.fields = dict(fields)
+        self.ctx = ctx
         self.seconds: float | None = None
         self._t0: float | None = None
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                self.name, **{k: v for k, v in self.fields.items()
+                              if isinstance(v, (str, int, float))})
+            self._annotation.__enter__()
         self._t0 = self.registry.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.seconds = self.registry.clock() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if exc_type is not None:
             self.fields.setdefault("error", f"{exc_type.__name__}: {exc}")
         self.registry.histogram(self.name).observe(self.seconds)
-        self.registry.emit({"kind": "span", "name": self.name, "labels": {},
-                            "seconds": self.seconds, "fields": self.fields})
+        self.registry.emit(span_record(self.name, self.seconds, self.fields,
+                                       self.ctx))
+
+
+def span_record(name: str, seconds: float, fields: dict, ctx=None) -> dict:
+    """The JSONL ``span`` record of one completed span: trace ids (when
+    ``ctx`` is given) at the top level, ``None`` fields left out."""
+    return {"kind": "span", "name": name, "labels": {}, "seconds": float(seconds),
+            **(trace_ids(ctx) if ctx is not None else {}),
+            "fields": {k: v for k, v in fields.items() if v is not None}}
+
+
+def trace_ids(ctx) -> dict:
+    """A trace context's ids as a record's top-level keys."""
+    ids = {"trace_id": ctx.trace_id, "span_id": ctx.span_id}
+    if ctx.parent_id is not None:
+        ids["parent_id"] = ctx.parent_id
+    return ids
 
 
 class Registry:
@@ -315,7 +357,8 @@ class Registry:
     # ------------------------------------------------------------- series
 
     def _get(self, cls, name: str, labels: dict, **kw):
-        key = (name, tuple(sorted((str(k), str(v)) for k, v in labels.items())))
+        key = (name, tuple(sorted((str(k), str(v)) for k, v in labels.items()))
+                     if labels else ())
         with self._lock:
             m = self._series.get(key)
             if m is None:
@@ -341,10 +384,11 @@ class Registry:
         return self._get(Histogram, name, labels,
                          base=base, growth=growth, n_buckets=n_buckets)
 
-    def span(self, name: str, **fields) -> Span:
+    def span(self, name: str, *, ctx=None, **fields) -> Span:
         """Span context manager timing into histogram ``name``; ``fields``
-        annotate the emitted event (not the series labels)."""
-        return Span(self, name, fields)
+        annotate the emitted event (not the series labels); ``ctx`` places it
+        in a causal trace."""
+        return Span(self, name, fields, ctx)
 
     # -------------------------------------------------------------- reads
 

@@ -43,7 +43,8 @@ import threading
 import time
 from typing import NamedTuple, Optional
 
-from .registry import Registry, default_registry
+from .registry import Registry, Span, default_registry, span_record
+from .registry import trace_ids as _trace_fields
 
 __all__ = [
     "TraceContext",
@@ -115,22 +116,13 @@ class TraceContext(NamedTuple):
         return cls(tid, sid, extra.get("parent_id"))
 
 
-def _trace_fields(ctx: TraceContext) -> dict:
-    fields = {"trace_id": ctx.trace_id, "span_id": ctx.span_id}
-    if ctx.parent_id is not None:
-        fields["parent_id"] = ctx.parent_id
-    return fields
-
-
 def emit_span(registry: Registry, name: str, ctx: TraceContext,
               seconds: float, **attrs) -> None:
     """Record one completed traced span: observes ``seconds`` into the
     histogram ``name`` and emits a ``span`` record (trace ids at top level,
     ``attrs`` under ``fields``) to the registry's sink."""
     registry.histogram(name).observe(seconds)
-    registry.emit({"kind": "span", "name": name, "labels": {},
-                   "seconds": float(seconds), **_trace_fields(ctx),
-                   "fields": {k: v for k, v in attrs.items() if v is not None}})
+    registry.emit(span_record(name, seconds, attrs, ctx))
 
 
 def emit_event(registry: Registry, name: str, ctx: TraceContext,
@@ -142,34 +134,13 @@ def emit_event(registry: Registry, name: str, ctx: TraceContext,
                    "fields": {k: v for k, v in attrs.items() if v is not None}})
 
 
-class TracedSpan:
-    """Context manager timing one phase into a traced span.
-
-    Like :class:`~repro.telemetry.registry.Span` but carries a
-    :class:`TraceContext` and — critically — closes on the exception path
-    too: a raise inside the block still observes the histogram and emits the
-    span record, with an ``error`` attribute naming the exception.
-    """
-
-    def __init__(self, registry: Registry, name: str, ctx: TraceContext,
-                 **attrs):
-        self.registry = registry
-        self.name = name
-        self.ctx = ctx
-        self.attrs = dict(attrs)
-        self.seconds: Optional[float] = None
-        self._t0: Optional[float] = None
-
-    def __enter__(self) -> "TracedSpan":
-        self._t0 = self.registry.clock()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        self.seconds = self.registry.clock() - self._t0
-        if exc_type is not None:
-            self.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
-        emit_span(self.registry, self.name, self.ctx, self.seconds,
-                  **self.attrs)
+def TracedSpan(registry: Registry, name: str, ctx: TraceContext,
+               **attrs) -> Span:
+    """The registry's :class:`~repro.telemetry.registry.Span` placed in a
+    causal trace: it times one phase into the histogram ``name``, emits its
+    record with ``ctx``'s ids and ``attrs`` as fields, and closes on the
+    exception path too, with an ``error`` field naming the exception."""
+    return Span(registry, name, attrs, ctx)
 
 
 class RequestTracer:

@@ -542,3 +542,99 @@ class TestBatcherSoak:
                                   np.zeros(b.rows, np.int32)))
         assert reg.value("serve.batches", bucket="k4") == 1
         assert reg.get("serve.latency_seconds", bucket="all").count == 1
+
+
+# ---------------------------------------------------------------------------
+# Names in the chip trace: device scopes, kernel names, profiler spans
+# ---------------------------------------------------------------------------
+
+GADGET_SCOPES = ("gadget.half_step", "gadget.project", "gadget.push_sum_mix",
+                 "gadget.average", "gadget.eps_check", "gadget.consensus",
+                 "gadget.objective")
+
+
+def _pallas_names(jaxpr) -> list:
+    """Names of every ``pallas_call`` in a jaxpr, in order."""
+    return [eqn.params["name"] for eqn in jaxpr.eqns
+            if eqn.primitive.name == "pallas_call"]
+
+
+class TestTraceNames:
+    @pytest.mark.parametrize("sparse", [False, True])
+    def test_lowered_segment_carries_every_scope(self, sparse):
+        from repro.core import gadget as gd
+        from repro.sparse.formats import EllPartitions
+
+        X, y = _toy_parts()
+        if sparse:
+            rng = np.random.default_rng(1)
+            cols = rng.integers(0, 24, size=(4, 16, 3)).astype(np.int32)
+            X = EllPartitions(cols, rng.random((4, 16, 3)).astype(np.float32), 24)
+        cfg = GadgetConfig(lam=1e-2, batch_size=2, gossip_rounds=2, max_iters=8,
+                           epsilon=0.0, topology="random", use_kernels=False)
+        Xd, m, n_i, d, _ = gd._unpack_partitions(X)
+        seg = gd._make_segment_train(cfg, m, n_i, d, 4)
+        data_key, mix_key = gd._stream_keys(0)
+        W = jnp.zeros((m, d))
+        text = seg.lower(Xd, y, None, data_key, mix_key, jnp.full((m,), 16.0),
+                         W, W, jnp.int32(1)).as_text(debug_info=True)
+        for scope in GADGET_SCOPES:
+            assert scope in text, scope
+
+    def test_kernel_entry_points_name_their_pallas_calls(self):
+        t = jnp.float32(1.0)
+        W = jnp.zeros((2, 300))
+        X = jnp.ones((2, 1, 300))
+        cols = jnp.zeros((2, 1, 6), jnp.int32)
+        vals = jnp.ones((2, 1, 6))
+        y = jnp.ones((2, 1))
+        qc, qv = jnp.zeros((3, 6), jnp.int32), jnp.ones((3, 6))
+        cases = {
+            "fleet_half_step": (lambda: hinge_ops.fleet_half_step(
+                W, X, y, lam=0.1, t=t, interpret=True), ["fleet_half_step"]),
+            "local_half_step": (lambda: hinge_ops.local_half_step(
+                W[0], X[0], y[0], lam=0.1, t=t, interpret=True),
+                ["local_half_step_margins", "local_half_step_update"]),
+            "ell_prefetch": (lambda: hinge_ops.ell_fleet_half_step(
+                W, cols, vals, y, lam=0.1, t=t, schedule="prefetch", interpret=True),
+                ["ell_fleet_half_step_gather", "ell_fleet_half_step_update"]),
+            "ell_sweep": (lambda: hinge_ops.ell_fleet_half_step(
+                W, cols, vals, y, lam=0.1, t=t, schedule="sweep", interpret=True),
+                ["ell_fleet_half_step_sweep_gather", "ell_fleet_half_step_sweep_update"]),
+            "dense_predict": (lambda: hinge_ops.dense_predict(
+                W[0], X[0], interpret=True), ["dense_predict"]),
+            "ell_predict": (lambda: hinge_ops.ell_predict(
+                W[0], qc, qv, interpret=True), ["ell_predict"]),
+        }
+        for case, (fn, names) in cases.items():
+            assert _pallas_names(jax.make_jaxpr(fn)().jaxpr) == names, case
+
+    def test_segment_spans_land_in_the_profiler_trace(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        X, y = _toy_parts()
+        cfg = GadgetConfig(lam=1e-2, batch_size=2, gossip_rounds=2,
+                           max_iters=8, epsilon=0.0, use_kernels=False)
+        list(gadget_train_stream(X, y, cfg, segment_iters=4))   # compile
+        opts = jax.profiler.ProfileOptions()
+        opts.host_tracer_level = 1
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            list(gadget_train_stream(X, y, cfg, segment_iters=4,
+                                     trace_registry=Registry()))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        events = [(ev.name, dict(ev.stats)) for plane in ProfileData.from_file(str(path)).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("train.")]
+        names = [n for n, _ in events]
+        assert names.count("train.segment") == 2
+        assert names.count("train.readback") == 10
+        for child in ("train.segment.dispatch", "train.segment.wait",
+                      "train.segment.account"):
+            assert names.count(child) == 2
+        assert {st.get("what") for n, st in events if n == "train.readback"} \
+            == {"t", "w_consensus", "epsilon", "objective", "mass"}

@@ -168,6 +168,75 @@ class TestTracedSpan:
 
 
 # ---------------------------------------------------------------------------
+# Segment-boundary spans of gadget_train_stream
+# ---------------------------------------------------------------------------
+
+SEGMENT_CHILDREN = ("train.segment.dispatch", "train.segment.wait",
+                    "train.readback", "train.segment.account")
+
+
+class TestSegmentSpans:
+    def _run(self, tmp_path, **kw):
+        X, y = _toy_parts()
+        reg, path = _sinked_registry(tmp_path)
+        segs = list(gadget_train_stream(X, y, _toy_cfg(max_iters=10),
+                                        segment_iters=5, trace=True,
+                                        trace_registry=reg, **kw))
+        return segs, reg, _records(reg, path)
+
+    def test_segment_holds_its_four_kinds_of_child_span(self, tmp_path):
+        segs, _, recs = self._run(tmp_path)
+        roots = [r for r in recs if r["name"] == "train.segment"]
+        assert len(roots) == len(segs) == 2
+        for seg, root in zip(segs, roots):
+            assert root["trace_id"] == seg.trace.trace_id
+            assert root["span_id"] == seg.trace.span_id
+            assert "parent_id" not in root
+            kids = [r for r in recs if r.get("parent_id") == root["span_id"]]
+            assert {r["name"] for r in kids} == set(SEGMENT_CHILDREN)
+            assert all(r["trace_id"] == root["trace_id"] for r in kids)
+            # nested: every child's time lies inside the segment's
+            assert sum(r["seconds"] for r in kids) <= root["seconds"]
+            assert all(r["ts"] <= root["ts"] for r in kids)
+            reads = [r["fields"]["what"] for r in kids if r["name"] == "train.readback"]
+            assert reads == ["t", "w_consensus", "epsilon", "objective", "mass"]
+            assert root["fields"]["iteration"] == seg.iteration
+
+    def test_readback_counter_counts_readback_spans(self, tmp_path):
+        segs, reg, recs = self._run(tmp_path)
+        spans = [r for r in recs if r["name"] == "train.readback"]
+        assert reg.value("train.host_readbacks") == len(spans) == 5 * len(segs)
+        X, y = _toy_parts()
+        reg2, path2 = _sinked_registry(tmp_path, "tele.jsonl")
+        segs = list(gadget_train_stream(X, y, _toy_cfg(max_iters=10),
+                                        segment_iters=5, trace_registry=reg2,
+                                        telemetry=tm.TrainTelemetry()))
+        spans = [r for r in _records(reg2, path2) if r["name"] == "train.readback"]
+        assert reg2.value("train.host_readbacks") == len(spans) == 9 * len(segs)
+
+    def test_spans_close_with_error_on_nonfinite_weights(self, tmp_path):
+        from repro.core.gadget import NonFiniteWeightsError
+
+        X, y = _toy_parts()
+        X = np.array(X)
+        X[0] = np.nan
+        reg, path = _sinked_registry(tmp_path)
+        with pytest.raises(NonFiniteWeightsError):
+            for _ in gadget_train_stream(X, y, _toy_cfg(max_iters=10),
+                                         segment_iters=5, trace=True,
+                                         trace_registry=reg):
+                pass
+        recs = _records(reg, path)
+        by_name = {r["name"]: r for r in recs}
+        for name in ("train.segment", "train.segment.account"):
+            assert by_name[name]["fields"]["error"].startswith(
+                "NonFiniteWeightsError"), name
+        assert by_name["train.segment.account"]["parent_id"] \
+            == by_name["train.segment"]["span_id"]
+        assert "error" not in by_name["train.segment.wait"]["fields"]
+
+
+# ---------------------------------------------------------------------------
 # RequestTracer: sampled fates, reservoir retention
 # ---------------------------------------------------------------------------
 
