@@ -563,8 +563,23 @@ def _trace_closures(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
     (masked full-data primal, dense or ELL gather-dot) and ``consensus_of(W)``
     (data-weighted network average). Built identically by the while-loop
     trainer, the segment trainer and the host reference so their traces agree
-    bit-for-bit."""
+    bit-for-bit. With kernels on, ELL planes go whole to the
+    ``ell_objective`` kernel; otherwise ``jnp.take`` over flat views, the
+    kernel's oracle."""
     total_n = jnp.sum(n_counts)
+
+    def consensus_of(W):
+        with jax.named_scope("gadget.consensus"):
+            return jnp.sum(W * n_counts[:, None], axis=0) / total_n
+
+    if isinstance(X, tuple) and cfg.use_kernels:
+        def objective_of(w):
+            with jax.named_scope("gadget.objective"):
+                return hinge_ops.ell_objective(w, *X, y, n_counts, lam=cfg.lam,
+                                               total=total_n)
+
+        return objective_of, consensus_of
+
     with jax.named_scope("gadget.objective"):  # the full-data pass's flat views
         y_flat = y.reshape(m * n_i)
         valid_flat = _valid_row_mask(m, n_i, n_counts)
@@ -578,10 +593,6 @@ def _trace_closures(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
     def objective_of(w):
         with jax.named_scope("gadget.objective"):
             return primal(w, *flat, y_flat, cfg.lam, valid_flat, total_n)
-
-    def consensus_of(W):
-        with jax.named_scope("gadget.consensus"):
-            return jnp.sum(W * n_counts[:, None], axis=0) / total_n
 
     return objective_of, consensus_of
 

@@ -26,6 +26,7 @@ from repro.telemetry import registry as tmr
 
 __all__ = ["pegasos_step", "local_half_step", "fleet_half_step",
            "ell_fleet_half_step", "ell_block_map", "resolve_ell_schedule",
+           "ell_objective",
            "dense_predict", "ell_predict", "resolve_block_cap",
            "padded_row_mask", "default_interpret",
            "launch_cost", "record_launch",
@@ -418,6 +419,30 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         with jax.named_scope("gadget.project"):
             W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
     return W_half.astype(W.dtype)
+
+
+def ell_objective(w: jax.Array, cols: jax.Array, vals: jax.Array,
+                  y: jax.Array, n_counts: jax.Array, *, lam: float,
+                  total: jax.Array, interpret: bool | None = None) -> jax.Array:
+    """Full-data primal objective over stacked ELL partitions, kernel-backed.
+
+    w: (d,) weights; cols/vals: the (m, n_i, k) partition planes as they are
+    stored; y: (m, n_i); n_counts: (m,) real rows per node (the rest are pad
+    rows); total: Σ n_counts. The same value as
+    ``svm_objective.primal_objective_masked_ell`` over the flattened planes
+    and the valid-row mask: ½λ‖w‖² + Σ hinge / total, with the hinge sum from
+    the ``ell_objective`` kernel (w resident in VMEM, no per-slot HBM
+    gather). Trace-safe, like the other wrappers."""
+    m, n_i, k = cols.shape
+    if k == 0:  # k_max=0 planes: one inert (0, 0) entry keeps blocks nonzero
+        cols = jnp.zeros((m, n_i, 1), jnp.int32)
+        vals = jnp.zeros((m, n_i, 1), jnp.float32)
+    if interpret is None:
+        interpret = default_interpret()
+    hinge = S.ell_objective(cols.astype(jnp.int32), vals.astype(jnp.float32),
+                            y.astype(jnp.float32), w.astype(jnp.float32),
+                            n_counts.astype(jnp.int32), interpret=interpret)
+    return 0.5 * lam * jnp.dot(w, w) + hinge / total
 
 
 # ------------------------------------------------------------------- predict

@@ -57,6 +57,19 @@ Two schedules per op:
     becomes O(touched · B·k·blk_d) instead of O(B·k·d) — proportional to the
     node's own nonzero structure, which is the GADGET paper's per-node-local
     cost model.
+
+The full-data objective pass (``ell_objective``) is a third kernel: the hinge
+sum over every row of every node's whole (n_i, k) partition, not a minibatch.
+Its w stays resident in VMEM as (⌈d/128⌉, 128) rows and each column id is
+resolved on chip: ``hi = col >> 7`` picks the row and ``lo = col & 127`` the
+lane, through an in-register lane gather (``take_along_axis``, Mosaic's
+``tpu.dynamic_gather``) of every w row, kept where ``hi`` matches. The scan
+visits all ⌈d/128⌉ rows for every block of 8 slots, so its cost depends on
+the shapes alone, not on the order of a row's slots. XLA stores a (m, n_i, k) plane with n_i as its
+lane dimension (k = 76 would pad to 128 lanes), so the kernel reads it through
+``swapaxes``, a bitcast rather than a copy, as (m, k, n_i) blocks: slots on
+sublanes, rows on lanes. A row's Σ_k is then a sublane reduction that lands
+lane-major beside its label.
 """
 from __future__ import annotations
 
@@ -68,10 +81,19 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 __all__ = ["ell_margins", "ell_grad_update", "ell_margins_prefetch",
-           "ell_grad_update_prefetch", "onehot_t", "contract_last",
-           "DEFAULT_BLK_D_SPARSE"]
+           "ell_grad_update_prefetch", "ell_objective", "onehot_t",
+           "contract_last", "DEFAULT_BLK_D_SPARSE"]
 
 DEFAULT_BLK_D_SPARSE = 512
+
+# Rows per ``ell_objective`` grid program (a lane multiple) and w rows per
+# step of its w-row scan: a step gathers OBJECTIVE_UNROLL rows for every
+# (8, 128) vreg of the tile's slot group, enough independent gathers to hide
+# a loop step's latency. On a v5e at CCAT's shape, 2,048 rows and 8 a step
+# take 115 ms a pass; 1,024 rows take 125 ms.
+OBJECTIVE_TILE = 2048
+OBJECTIVE_UNROLL = 8
+_LANES = 128
 
 
 def onehot_t(cols_row, base, blk_d: int):
@@ -305,3 +327,93 @@ def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
         interpret=interpret,
     )(block_ids, cols, coeff[:, :, None] * vals)
     return out.reshape(m, n_blocks_max, blk_d)
+
+
+# ---------------------------------------------------------------------------
+# Full-data objective pass: grid (m, ⌈n_i/tile⌉), w resident in VMEM
+# ---------------------------------------------------------------------------
+
+
+def _gather_w(w_ref, col):
+    """w[col] for one slot group's (≤ 8, tile) block of column ids, as its
+    (≤ 8, 128) vregs: a lane gather of every w row, kept where it is the
+    entry's row. Garbage lanes past the array's end match some row or none;
+    the caller masks their rows."""
+    hi, lo = col >> 7, col & (_LANES - 1)
+    unroll = OBJECTIVE_UNROLL
+    vregs = [(hi[:, c:c + _LANES], lo[:, c:c + _LANES])
+             for c in range(0, col.shape[1], _LANES)]
+
+    def rows(q, gs):  # ``unroll`` w rows a step (Mosaic unrolls no loop)
+        for u in range(unroll):
+            r = q * unroll + u
+            wr = jnp.broadcast_to(w_ref[pl.ds(r, 1), :], vregs[0][0].shape)
+            gs = tuple(jnp.where(h == r, jnp.take_along_axis(wr, l, axis=1), g)
+                       for (h, l), g in zip(vregs, gs))
+        return gs
+
+    zero = jnp.zeros(vregs[0][0].shape, jnp.float32)
+    return jax.lax.fori_loop(0, w_ref.shape[0] // unroll, rows,
+                             (zero,) * len(vregs))
+
+
+def _ell_objective_kernel(counts_ref, cols_ref, vals_ref, y_ref, w_ref, o_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+    k, tile = cols_ref.shape[1:]
+    lanes = [pl.ds(c, _LANES) for c in range(0, tile, _LANES)]
+    s = [jnp.zeros((1, _LANES), jnp.float32)] * len(lanes)
+    for g0 in range(0, k, 8):  # slot groups: the tile's rows of 8 slots
+        slots = pl.ds(g0, min(8, k - g0))
+        gs = _gather_w(w_ref, cols_ref[0, slots, :])
+        s = [s_c + jnp.sum(vals_ref[0, slots, lane] * g, axis=0, keepdims=True)
+             for s_c, lane, g in zip(s, lanes, gs)]
+    hinge = jnp.zeros((1, _LANES), jnp.float32)
+    for s_c, lane in zip(s, lanes):
+        margin = y_ref[0, :, lane] * s_c
+        row = (jax.lax.broadcasted_iota(jnp.int32, margin.shape, 1)
+               + j * tile + lane.start)
+        # where, never a multiply: rows past the array's end hold garbage
+        hinge += jnp.where(row < counts_ref[i], jnp.maximum(0.0, 1.0 - margin),
+                           0.0)
+    o_ref[0, 0] = hinge
+
+
+def ell_objective(cols: jax.Array, vals: jax.Array, y: jax.Array,
+                  w: jax.Array, counts: jax.Array, *,
+                  interpret: bool = False) -> jax.Array:
+    """Σ max(0, 1 − y·⟨w, x⟩) over the first ``counts[i]`` rows of every node.
+
+    cols/vals: the whole (m, n_i, k) int32/f32 partition planes; y: (m, n_i);
+    w: (d,) f32, zero-padded here to rows of 128, one block for the whole
+    grid; counts: (m,) int32 real rows per node, scalar-prefetched. Rows from
+    ``counts[i]`` on (pad rows, and the tail tile's out-of-bounds lanes) are
+    left out. Each (node, tile) program writes its 128 lane partials;
+    returns their sum."""
+    m, n_i, k = cols.shape
+    step = _LANES * OBJECTIVE_UNROLL  # the scan covers w in whole steps
+    w_rows = jnp.pad(w, (0, -w.shape[0] % step)).reshape(-1, _LANES)
+    n_hi = w_rows.shape[0]
+    tile = min(OBJECTIVE_TILE, -(-n_i // _LANES) * _LANES)
+    n_tiles = -(-n_i // tile)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(m, n_tiles),
+        in_specs=[
+            pl.BlockSpec((1, k, tile), lambda i, j, c: (i, 0, j)),
+            pl.BlockSpec((1, k, tile), lambda i, j, c: (i, 0, j)),
+            pl.BlockSpec((1, 1, tile), lambda i, j, c: (i, 0, j)),
+            pl.BlockSpec((n_hi, _LANES), lambda i, j, c: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, _LANES), lambda i, j, c: (i, j, 0, 0)),
+    )
+    out = pl.pallas_call(
+        _ell_objective_kernel,
+        name="ell_objective",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n_tiles, 1, _LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(counts, jnp.swapaxes(cols, 1, 2), jnp.swapaxes(vals, 1, 2),
+      y.reshape(m, 1, n_i), w_rows)
+    return jnp.sum(out)

@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.gadget import GadgetConfig, gadget_train, gadget_train_reference
+from repro.core.gadget import (GadgetConfig, gadget_train, gadget_train_reference,
+                               gadget_train_stream)
 from repro.data import libsvm, svm_datasets
 from repro.kernels.hinge_subgrad import ops as hinge_ops
 from repro.kernels.hinge_subgrad import ref as hinge_ref
@@ -233,6 +234,43 @@ class TestSparseKernels:
             jnp.pad(vals, ((0, 0), (0, 0), (0, 9))),
             y, lam=1e-2, t=t, interpret=True)
         np.testing.assert_allclose(np.asarray(base), np.asarray(wide), atol=1e-6)
+
+
+class TestObjectiveKernel:
+    @pytest.mark.parametrize("m,n_i,k,d,counts", [
+        (1, 300, 3, 200, [300]),                 # m = 1, one short tile
+        (3, 2500, 76, 1000, [2500, 1200, 7]),    # pad rows, a tile of none
+        (2, 2100, 3, 129, [2100, 2099]),         # d one past a lane row
+        (4, 640, 3, 4096, [640, 1, 600, 639]),   # d a lane multiple
+        (2, 200, 0, 200, [200, 150]),            # k_max = 0: every row empty
+    ])
+    def test_matches_masked_ell_oracle(self, m, n_i, k, d, counts):
+        """The ``ell_objective`` kernel equals ``primal_objective_masked_ell``
+        over the flattened planes: n_i past the 2,048-row tile or short of
+        it, pad rows past each node's count (y = 0, col 0, val 0, valid
+        false) and pad slots (col 0, val 0) in every row's last two
+        entries."""
+        from repro.core import svm_objective as obj
+
+        rng = np.random.default_rng(n_i)
+        cols = rng.integers(0, d, (m, n_i, k)).astype(np.int32)
+        vals = rng.normal(size=(m, n_i, k)).astype(np.float32)
+        y = np.sign(rng.normal(size=(m, n_i))).astype(np.float32)
+        cols[:, :, k - 2:], vals[:, :, k - 2:] = 0, 0.0
+        valid = np.arange(n_i)[None, :] < np.asarray(counts)[:, None]
+        cols[~valid], vals[~valid], y[~valid] = 0, 0.0, 0.0
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32) * 0.5)
+        n_counts = jnp.asarray(counts, jnp.float32)
+        total = jnp.sum(n_counts)
+
+        got = hinge_ops.ell_objective(w, jnp.asarray(cols), jnp.asarray(vals),
+                                      jnp.asarray(y), n_counts, lam=1e-3,
+                                      total=total, interpret=True)
+        want = obj.primal_objective_masked_ell(
+            w, jnp.asarray(cols.reshape(m * n_i, k)),
+            jnp.asarray(vals.reshape(m * n_i, k)), jnp.asarray(y.reshape(-1)),
+            1e-3, jnp.asarray(valid.reshape(-1)), total)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
 
 
 # ----------------------------------------------------- block-bucketed ELL
@@ -501,6 +539,23 @@ class TestSparseGadget:
         rj = gadget_train(Pe, jnp.asarray(yp), cfg._replace(use_kernels=False),
                           n_counts=nc)
         assert float(jnp.max(jnp.abs(rk.w_consensus - rj.w_consensus))) < 1e-4
+
+    def test_stream_objective_kernel_matches_jnp_path(self):
+        """Each segment's objective from the ``ell_objective`` kernel
+        (use_kernels=True) is within 1e-6 of the ``jnp.take`` pass's
+        (use_kernels=False), on partitions with pad rows."""
+        ds = svm_datasets.make_dataset("reuters", scale=0.05, seed=0, sparse=True)
+        Pe, yp, nc = svm_datasets.partition(ds.X_train, ds.y_train, 3, seed=3)
+        assert len(set(np.asarray(nc).tolist())) > 1  # a node has pad rows
+        cfg = GadgetConfig(lam=ds.lam, batch_size=4, gossip_rounds=2,
+                           max_iters=60, check_every=30, epsilon=0.0)
+        objectives = [
+            [s.objective for s in gadget_train_stream(
+                Pe, jnp.asarray(yp), cfg._replace(use_kernels=uk),
+                segment_iters=20, n_counts=nc)]
+            for uk in (True, False)]
+        assert len(objectives[0]) == 3
+        np.testing.assert_allclose(objectives[0], objectives[1], rtol=1e-6)
 
     def test_sparse_reference_oracle_agrees(self):
         ds, Pe, Xp, yp, nc = self._reuters_shaped(m=4)

@@ -3,10 +3,11 @@
 Every other test runs the kernels in interpret mode, which accepts block
 shapes and vector ops that the chip's compiler refuses. These tests hand each
 dispatch wrapper to the TPU compiler for a described (not attached) v5e chip
-at the deployment widths — CCAT (m = 10, B = 1, k = 76, d = 47,236) and
-webspam (d = 254) — and assert that the compiled program holds the Mosaic
-kernel (``tpu_custom_call``). A refused block layout, shape cast or VMEM
-overrun fails here, without a chip. Nothing runs, so no result is checked.
+at the deployment widths — CCAT (m = 10, B = 1, k = 76, d = 47,236; its
+objective pass over the whole 781,270-row partitions) and webspam (d = 254) —
+and assert that the compiled program holds the Mosaic kernel
+(``tpu_custom_call``). A refused block layout, shape cast or VMEM overrun
+fails here, without a chip. Nothing runs, so no result is checked.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and under pytest-xdist every
@@ -103,6 +104,18 @@ def test_ell_fleet_half_step_compiles(chip, name, schedule, one_node):
             W, c, v, y, lam=LAM, t=T, interpret=False, schedule=schedule),
         spec(chip, (m, d)), spec(chip, (m, B, k), jnp.int32),
         spec(chip, (m, B, k)), spec(chip, (m, B)))
+
+
+def test_ell_objective_compiles(chip):
+    """The full-data objective pass at CCAT's whole partitions: (m, n_i, k)
+    planes with n_i = 78,127 rows a node, w resident in VMEM."""
+    m, k, d, n_i = 10, 76, 47236, 78127
+    compile_for_chip(
+        lambda w, c, v, y, n: ops.ell_objective(w, c, v, y, n, lam=LAM,
+                                                total=jnp.sum(n),
+                                                interpret=False),
+        spec(chip, (d,)), spec(chip, (m, n_i, k), jnp.int32),
+        spec(chip, (m, n_i, k)), spec(chip, (m, n_i)), spec(chip, (m,)))
 
 
 @pytest.mark.parametrize("name", ["ccat", "webspam"])
