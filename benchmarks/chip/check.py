@@ -3,17 +3,18 @@
 Training (the first segments of the first run, which set-up drove through the
 window's own call, against ``reference.train_segments`` from the same seed):
 
-  objective_gap     max over the segments of |f_prog − f_ref| / |f_ref|, f the
-                    primal objective of the segment's consensus
+  objective_gap     max over the segments (and classes) of |f_prog − f_ref| /
+                    |f_ref|, f the primal objective of the segment's consensus
   first_update_gap  after the first segment, the worst leaf's gap of norms of
                     the change from W = 0: |‖Δp‖ − ‖Δr‖| / max(‖Δr‖, median ‖Δr‖)
   change_gap        the same after the last checked segment
   state_dist        after the last checked segment, the worst leaf's
                     ‖p − r‖ / max(‖r‖, median ‖r‖)
 
-A leaf is one node's row of W or of W_sum. Leaves whose reference change is
-under a thousandth of the median leaf's are left out (none are at these
-settings: every node's row moves).
+A leaf is one node's row of W or of W_sum; with a class axis, (m, C, d), one
+(node, class) row, so one class's error is not hidden by the others'. Leaves
+whose reference change is under a thousandth of the median leaf's are left out
+(none are at these settings: every node's row moves).
 
 Serving (every request due in the window, against ``reference.scores`` on the
 plane that was live when its drain ran):
@@ -35,7 +36,7 @@ import reference
 
 
 def _leaves(W, W_sum):
-    return list(np.asarray(W)) + list(np.asarray(W_sum))
+    return [row for a in (W, W_sum) for row in np.asarray(a).reshape(-1, np.shape(a)[-1])]
 
 
 def worst_norm_gap(prog, ref) -> float:
@@ -56,7 +57,8 @@ def worst_dist(prog, ref) -> float:
 
 def training_numbers(checked, ref) -> dict:
     """``checked`` and ``ref``: one (W, W_sum, objective) per segment."""
-    obj = max(abs(p[2] - r[2]) / abs(r[2]) for p, r in zip(checked, ref))
+    obj = max(float(np.max(np.abs(np.asarray(p[2]) - r[2]) / np.abs(r[2])))
+              for p, r in zip(checked, ref))
     first = worst_norm_gap(_leaves(*checked[0][:2]), _leaves(*ref[0][:2]))
     last_p, last_r = _leaves(*checked[-1][:2]), _leaves(*ref[-1][:2])
     return {"objective_gap": obj, "first_update_gap": first,

@@ -18,6 +18,20 @@ the ones it got (the rest is padding). Dense rows (``layout: "dense"``) hold
 are scaled to unit norm, and labels threshold y = x·w* at the class-balance
 quantile, then flip with the label noise.
 
+With ``n_classes`` C in the ``dataset`` block the rows are drawn the same way
+and each valid row gets one of C classes instead: the argmax over c of its
+planted margin x·w*_c, standardized over the split's valid rows, plus a
+per-class offset; W* (C, d) is N(0, 1). The configuration states its class
+profile as ``class_shares`` (C positive numbers, normalized by their sum, so a
+source's per-class row counts may be given as they are), and the offsets are
+fitted so that the argmax gives each class its share of the split's valid
+rows, as the class-balance quantile does for two classes (``fit_offsets``).
+Then, with probability label_noise, the class is replaced by a uniformly drawn
+other one, as two-class labels flip after their threshold. ``y`` is then the
+one-vs-rest layout (…, C): +1 for the row's class, −1 for every other, 0 on
+padded rows. Margins are computed in row chunks (``CHUNK_MARGINS``), so no
+(rows, k, C) temporary is made.
+
 A serving mix may give the test rows a length distribution (``query_nnz``):
 each row then keeps the first L distinct draws, L drawn from the seed, with
 enough draws per row (``alias_draws``) for the longest.
@@ -33,6 +47,13 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 ALIAS_DRAWS = 384      # per sparse row; 76 distinct Zipf(1.25) columns need ≤ 260
 CHUNK_ROWS = 32768     # sparse rows deduplicated per map step
+CHUNK_MARGINS = 4096   # sparse rows per map step of the C-class margins
+BINARY_KEYS = ("layout", "n_train", "n_test", "d", "nnz_per_row", "col_skew",
+               "label_noise", "class_balance")
+CLASS_KEYS = ("layout", "n_train", "n_test", "d", "nnz_per_row", "col_skew",
+              "label_noise", "n_classes", "class_shares")
+FIT_STEPS = 200        # annealed Sinkhorn steps that fit the class offsets
+FIT_TAUS = (0.3, 0.005)  # their first and last temperature, on unit-variance margins
 
 
 def node_counts(n: int, m: int) -> tuple[np.ndarray, int]:
@@ -124,6 +145,72 @@ def _labels(key, margin, valid, spec):
     return jnp.where(valid, jnp.where(flip, -y, y), 0.0).astype(jnp.float32)
 
 
+def class_shares(spec) -> np.ndarray:
+    """(C,) the configuration's ``class_shares``, normalized by their sum."""
+    p = np.asarray(spec["class_shares"], np.float64)
+    if p.shape != (spec["n_classes"],) or not np.all(p > 0):
+        raise ValueError(f"class_shares must be n_classes = {spec['n_classes']} "
+                         f"positive numbers, got {spec['class_shares']!r}")
+    return p / p.sum()
+
+
+def fit_offsets(z, w, shares):
+    """(C,) offsets b at which the argmax over c of z + b, over the rows (n, C)
+    weighted by w (n,), gives each class its share: annealed Sinkhorn, which
+    moves b until the soft argmax softmax((z + b)/τ) gives every class its
+    share while τ falls geometrically over FIT_TAUS in FIT_STEPS steps; at the
+    last τ the soft and the hard argmax part a handful of rows."""
+    log_p = jnp.log(jnp.asarray(shares, jnp.float32))
+    n = jnp.sum(w)
+
+    def step(b, tau):
+        soft = jax.nn.softmax((z + b) / tau, axis=1)
+        s = jnp.sum(soft * w[:, None], axis=0) / n
+        return b + tau * (log_p - jnp.log(jnp.maximum(s, 1e-30))), None
+
+    taus = jnp.asarray(np.geomspace(*FIT_TAUS, FIT_STEPS), jnp.float32)
+    return jax.lax.scan(step, jnp.zeros(z.shape[1], jnp.float32), taus)[0]
+
+
+def _class_margins(rows, w_star):
+    """(n_rows, C) margins x·w*_c: ELL rows gather rows of W*ᵀ (d, C) by chunks
+    of CHUNK_MARGINS rows; dense rows take one matmul."""
+    if not isinstance(rows, tuple):
+        return jnp.matmul(rows, w_star.T, precision=HIGHEST)
+    cols, vals = rows
+    n_rows = cols.shape[0]
+    n_chunks = -(-n_rows // CHUNK_MARGINS)
+    pad = ((0, n_chunks * CHUNK_MARGINS - n_rows), (0, 0))
+    wt = w_star.T
+
+    def chunk(args):
+        c, v = args
+        return jnp.einsum("rk,rkc->rc", v, wt[c], precision=HIGHEST)
+
+    out = jax.lax.map(chunk, (jnp.pad(cols, pad).reshape(n_chunks, CHUNK_MARGINS, -1),
+                              jnp.pad(vals, pad).reshape(n_chunks, CHUNK_MARGINS, -1)))
+    return out.reshape(n_chunks * CHUNK_MARGINS, -1)[:n_rows]
+
+
+def _class_labels(key, margin, valid, spec):
+    """One-vs-rest ±1 labels (n_rows, C) of the argmax of the standardized
+    margins plus the fitted class offsets; a label_noise share moves to
+    another class."""
+    C = spec["n_classes"]
+    w = valid.astype(jnp.float32)[:, None]
+    n = jnp.sum(w)
+    mean = jnp.sum(margin * w, axis=0) / n
+    std = jnp.sqrt(jnp.sum(jnp.square(margin - mean) * w, axis=0) / n)
+    z = (margin - mean) / jnp.maximum(std, 1e-30)
+    cls = jnp.argmax(z + fit_offsets(z, w[:, 0], spec["class_shares"]), axis=1).astype(jnp.int32)
+    k_flip, k_other = jax.random.split(key)
+    other = jax.random.randint(k_other, cls.shape, 0, C - 1)
+    other = other + (other >= cls)
+    cls = jnp.where(jax.random.uniform(k_flip, cls.shape) < spec["label_noise"], other, cls)
+    y = jnp.where(cls[:, None] == jnp.arange(C)[None, :], 1.0, -1.0)
+    return jnp.where(valid[:, None], y, 0.0).astype(jnp.float32)
+
+
 def _unit_rows(vals):
     return vals / jnp.maximum(jnp.linalg.norm(vals, axis=-1, keepdims=True), 1e-8)
 
@@ -143,10 +230,15 @@ def _split(key, spec, n_rows, valid, w_star, tables, query=None):
         vals = jnp.where(live, jnp.abs(jax.random.normal(k_vals, (n_rows, nnz))), 0.0)
         vals = _unit_rows(vals)
         cols = jnp.where(live, cols, 0)
+        if "n_classes" in spec:
+            return (cols, vals), _class_labels(k_lab, _class_margins((cols, vals), w_star),
+                                               valid, spec)
         margin = jnp.sum(vals * w_star[cols], axis=-1)
         return (cols, vals), _labels(k_lab, margin, valid, spec)
     mask = _uniform_mask(k_cols, n_rows, d, nnz) & valid[:, None]
     X = _unit_rows(jnp.where(mask, jnp.abs(jax.random.normal(k_vals, (n_rows, d))), 0.0))
+    if "n_classes" in spec:
+        return X, _class_labels(k_lab, _class_margins(X, w_star), valid, spec)
     margin = jnp.matmul(X, w_star, precision=HIGHEST)
     return X, _labels(k_lab, margin, valid, spec)
 
@@ -156,13 +248,16 @@ def _make(key, spec_items, m, with_train, with_test, tables, query_items=None):
     spec = dict(spec_items)
     query = dict(query_items) if query_items else None
     k_w, k_train, k_test = jax.random.split(key, 3)
-    w_star = jnp.abs(jax.random.normal(k_w, (spec["d"],)))
+    if "n_classes" in spec:
+        w_star = jax.random.normal(k_w, (spec["n_classes"], spec["d"]))
+    else:
+        w_star = jnp.abs(jax.random.normal(k_w, (spec["d"],)))
     out = {}
     if with_train:
         counts, n_i = node_counts(spec["n_train"], m)
         valid = (jnp.arange(n_i)[None, :] < jnp.asarray(counts)[:, None]).reshape(-1)
         X, y = _split(k_train, spec, m * n_i, valid, w_star, tables)
-        out["y"] = y.reshape(m, n_i)
+        out["y"] = y.reshape((m, n_i) + y.shape[1:])
         if spec["layout"] == "ell":
             out["cols"], out["vals"] = (a.reshape(m, n_i, -1) for a in X)
         else:
@@ -179,7 +274,8 @@ def make_data(seed: int, dataset: dict, m: int, *, with_train: bool = True,
     """A configuration's device arrays from ``seed`` in one jitted call.
 
     Returns a dict of device arrays — with ``with_train`` the node partitions
-    ``cols``/``vals`` (m, n_i, k) or ``X`` (m, n_i, d) and ``y`` (m, n_i), with
+    ``cols``/``vals`` (m, n_i, k) or ``X`` (m, n_i, d) and ``y`` (m, n_i), or
+    (m, n_i, C) with ``dataset["n_classes"]`` C (one-vs-rest ±1), with
     ``with_test`` the test split's ``test_cols``/``test_vals``/``test_y`` (ELL
     only) — plus the host-side ``counts`` (m,) of valid rows per node. Each
     split's rows are the same whether or not the other split is made.
@@ -189,9 +285,9 @@ def make_data(seed: int, dataset: dict, m: int, *, with_train: bool = True,
     configuration's fixed nonzeros per row; the test planes are then ``max``
     wide. The training split does not change.
     """
-    spec = {k: dataset[k] for k in ("layout", "n_train", "n_test", "d",
-                                    "nnz_per_row", "col_skew", "label_noise",
-                                    "class_balance")}
+    spec = {k: dataset[k] for k in (CLASS_KEYS if "n_classes" in dataset else BINARY_KEYS)}
+    if "n_classes" in spec:
+        spec["class_shares"] = tuple(float(p) for p in class_shares(spec))
     tables = None
     if spec["layout"] == "ell":
         tables = tuple(jnp.asarray(a) for a in alias_table(spec["d"], spec["col_skew"]))

@@ -4,7 +4,8 @@ Per iteration the sparse half-step of m nodes on B rows of k nonzeros needs
 at the least (``required``): every entry of the sampled rows read once
 (column id and value, 8 B) and its label (4 B), m·B·(8k + 4) B; the weights
 those entries touch read and written once, 8·m·B·k B; and 4·m·B·k flops
-(margin and sub-gradient). The share is iterations × max(flops / peak FLOP/s,
+(margin and sub-gradient); with C = ``dataset.n_classes`` classes (default 1)
+the touched weights and the flops count C times. The share is iterations × max(flops / peak FLOP/s,
 bytes / peak HBM B/s) ÷ the device seconds of the leaf operations the
 kernels' ``pallas_call`` names find (``ell_fleet_half_step_gather`` and
 ``_update``, or their ``sweep_`` twins) in the traced stretch. Moves
@@ -17,8 +18,8 @@ KERNELS = r"(?<!\w)ell_fleet_half_step_(?:sweep_)?(?:gather|update)(?!\w)"
 
 def required(config):
     m, B = config["gadget"]["n_nodes"], config["gadget"]["batch_size"]
-    k = config["dataset"]["nnz_per_row"]
-    return 4 * m * B * k, m * B * (8 * k + 4) + 8 * m * B * k
+    k, C = config["dataset"]["nnz_per_row"], config["dataset"].get("n_classes", 1)
+    return 4 * m * B * k * C, m * B * (8 * k + 4) + 8 * m * B * k * C
 
 
 def read(ctx):

@@ -4,10 +4,12 @@ Each segment's full-data objective pass over n_train rows of k nonzeros needs
 at the least (``required``): every entry read once (column id and value,
 8 B) and every label (4 B), n_train·(8k + 4) B, and w read once, 4·d B; and
 n_train·(2k + 4) flops (a multiply-add per entry, then the label product,
-the hinge and the sum). The share is segments × max(flops / peak FLOP/s,
-bytes / peak HBM B/s) ÷ the device seconds of the leaf operations the
-kernel's ``pallas_call`` name (``ell_objective``) finds in the traced
-stretch; null where no such operation ran (the ``jnp.take`` pass, or a dense
+the hinge and the sum). With C = ``dataset.n_classes`` classes (default 1) W is
+read once, 4·C·d B, and each row's flops count C times, n_train·C·(2k + 4);
+the entries and labels are read once whatever C is. The share is segments ×
+max(flops / peak FLOP/s, bytes / peak HBM B/s) ÷ the device seconds of the
+leaf operations the kernel's ``pallas_call`` name (``ell_objective``) finds in
+the traced stretch; null where no such operation ran (the ``jnp.take`` pass, or a dense
 configuration). Moves ``train_samples_per_s``.
 """
 import scopes
@@ -17,8 +19,8 @@ KERNEL = r"(?<!\w)ell_objective(?!\w)"
 
 def required(config):
     ds = config["dataset"]
-    n, k, d = ds["n_train"], ds["nnz_per_row"], ds["d"]
-    return n * (2 * k + 4), n * (8 * k + 4) + 4 * d
+    n, k, d, C = ds["n_train"], ds["nnz_per_row"], ds["d"], ds.get("n_classes", 1)
+    return n * C * (2 * k + 4), n * (8 * k + 4) + 4 * C * d
 
 
 def read(ctx):

@@ -3,7 +3,9 @@
 Per iteration the dense half-step of m nodes on B rows of width d needs at
 the least (``required``): every entry of the sampled rows read once (4 B) and
 its label (4 B), m·B·(4d + 4) B; the weights read and written once,
-8·m·B·d B; and 4·m·B·d flops (margin and sub-gradient). The share is
+8·m·B·d B; and 4·m·B·d flops (margin and sub-gradient); with C =
+``dataset.n_classes`` classes (default 1) the weights and the flops count C
+times. The share is
 iterations × max(flops / peak FLOP/s, bytes / peak HBM B/s) ÷ the device
 seconds of the leaf operations the kernel's ``pallas_call`` name
 (``fleet_half_step``) finds in the traced stretch. Moves
@@ -16,8 +18,8 @@ KERNEL = r"(?<!\w)fleet_half_step(?!\w)"
 
 def required(config):
     m, B = config["gadget"]["n_nodes"], config["gadget"]["batch_size"]
-    d = config["dataset"]["d"]
-    return 4 * m * B * d, m * B * (4 * d + 4) + 8 * m * B * d
+    d, C = config["dataset"]["d"], config["dataset"].get("n_classes", 1)
+    return 4 * m * B * d * C, m * B * (4 * d + 4) + 8 * m * B * d * C
 
 
 def read(ctx):

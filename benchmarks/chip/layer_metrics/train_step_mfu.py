@@ -12,7 +12,10 @@ Per iteration the paper's step needs at the least (``required``):
          every weight (2 per weight), the m×m mix (2·m per weight) and the two
          projections (3 per weight each)
 
-At B = 1 the bytes bind by far. The share is
+With C = ``dataset.n_classes`` one-vs-rest classes (default 1) the weights are
+(m, C, d): the touched weights, the plane, the ε-check and every weight's flops
+count C times; the data does not, a label being one class id of 4 B whatever
+implements it. At B = 1 the bytes bind by far. The share is
 max(flops / peak FLOP/s, bytes / peak HBM B/s) × iterations of the segments
 in the traced stretch / those segments' seconds on the host clock. The
 full-data objective pass at each ε-check is left out: the stopping rule does
@@ -22,14 +25,14 @@ not need it. Moves ``train_samples_per_s``.
 
 def required(config):
     ds, g = config["dataset"], config["gadget"]
-    m, B, d = g["n_nodes"], g["batch_size"], ds["d"]
+    m, B, d, C = g["n_nodes"], g["batch_size"], ds["d"], ds.get("n_classes", 1)
     ell = ds["layout"] == "ell"
     k = ds["nnz_per_row"] if ell else d
     entry_bytes = 8 if ell else 4
     data = m * B * (k * entry_bytes + 4)
-    touched = 2 * 4 * m * B * k
-    plane = 2 * 4 * m * d + 2 * 4 * m * d / g["check_every"]
-    flops = 4 * m * B * k + m * d * (2 + 2 * m + 6)
+    touched = 2 * 4 * m * B * k * C
+    plane = 2 * 4 * m * C * d + 2 * 4 * m * C * d / g["check_every"]
+    flops = 4 * m * B * k * C + m * C * d * (2 + 2 * m + 6)
     return flops, data + touched + plane
 
 

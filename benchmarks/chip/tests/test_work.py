@@ -39,6 +39,49 @@ def test_train_step_webspam():
     assert bytes_bind(flops, nbytes)
 
 
+# rcv1.multiclass (LibSVM; RCV1-v2, Lewis et al., JMLR 2004) trained on its
+# 518,571-row split, one-vs-rest over 53 classes, at the paper's CCAT settings
+RCV1_MULTICLASS = {
+    "dataset": dict(CCAT["dataset"], n_train=518571, n_classes=53),
+    "gadget": CCAT["gadget"],
+}
+
+
+def test_train_step_rcv1_multiclass():
+    flops, nbytes = reader_module("train_step_mfu").required(RCV1_MULTICLASS)
+    # rows: 10 × (76 × 8 + 4); touched: 8 × 10 × 76 × 53;
+    # plane and ε-check: 8 × 10 × 53 × 47,236 × (1 + 1/200)
+    assert nbytes == pytest.approx(6120 + 322_240 + 201_282_043.2)
+    assert round(nbytes) == 201_610_403
+    assert flops == 4 * 10 * 76 * 53 + 10 * 53 * 47236 * 28 == 701_143_360
+    assert bytes_bind(flops, nbytes)
+
+
+def test_objective_pass_rcv1_multiclass():
+    flops, nbytes = reader_module("ell_objective_roofline").required(RCV1_MULTICLASS)
+    assert nbytes == 518571 * (8 * 76 + 4) + 4 * 53 * 47236 == 327_379_484
+    assert flops == 518571 * 53 * (2 * 76 + 4) == 4_287_545_028
+
+
+def test_half_steps_count_every_class():
+    ell = reader_module("ell_fleet_half_step_roofline").required
+    assert ell(RCV1_MULTICLASS) == (4 * 10 * 76 * 53, 10 * (8 * 76 + 4) + 8 * 10 * 76 * 53)
+    dense = reader_module("fleet_half_step_roofline").required
+    webspam_classes = {"dataset": dict(WEBSPAM["dataset"], n_classes=53),
+                       "gadget": WEBSPAM["gadget"]}
+    assert dense(webspam_classes) == (4 * 10 * 254 * 53, 10 * (4 * 254 + 4) + 8 * 10 * 254 * 53)
+
+
+@pytest.mark.parametrize("name", ["train_step_mfu", "ell_fleet_half_step_roofline",
+                                  "fleet_half_step_roofline", "ell_objective_roofline"])
+@pytest.mark.parametrize("config", [CCAT, WEBSPAM], ids=["ccat", "webspam"])
+def test_one_class_is_the_binary_count(name, config):
+    """``n_classes`` 1 counts exactly what a configuration without it does."""
+    one = {"dataset": dict(config["dataset"], n_classes=1), "gadget": config["gadget"]}
+    req = reader_module(name).required
+    assert req(one) == req(config)
+
+
 def test_sparse_scoring_ccat():
     import numpy as np
 

@@ -155,12 +155,13 @@ class Trainer:
 
 def checked_segments(trainer) -> list:
     """Set-up: the first segments through the window's own call, kept for the
-    reference (per-node W and W_sum on the host, and the objective)."""
+    reference (per-node W and W_sum on the host, and the objective, one per
+    class where there are classes, as float64)."""
     out = []
     for _ in range(CHECKED_SEGMENTS):
         seg, _ = trainer.next_segment()
         out.append((np.asarray(seg.W, np.float64), np.asarray(seg.W_sum, np.float64),
-                    float(seg.objective)))
+                    np.asarray(seg.objective, np.float64)))
     return out
 
 
