@@ -50,6 +50,17 @@ The sparse half-step is inherently fleet-wide (one launch for all m nodes);
 mode. At CCAT sparsity (0.16%) this is the difference between a ~147 GB dense
 train split and ~0.5 GB of planes — the full-shape paper scenario fits.
 
+One-vs-rest classes: labels of shape (m, n_i, C), ±1 per class (0 on padded
+rows), in place of the binary (m, n_i), train C fleets in one run. W is then
+(m, C, d): every node draws one row-id stream that all classes share, the
+half-step runs each class on the node's same rows (one gather of a row's
+columns serves every class in the ELL kernels), the Push-Sum mix is one
+matmul over the (m, C·d) values with one mass per node, each (node, class)
+row is projected on its own, and ε is the largest ‖ΔW‖ over (node, class)
+rows, so a run stops when every class's fleet has converged. The consensus is
+(C, d) and the objective a (C,) array, one primal objective per class. The
+choice is made from the label plane's shape alone.
+
 Weighted consensus: the paper pushes n_i·ŵ_i so the consensus target is the
 data-weighted network average Σ n_i ŵ_i / N. We implement this by initializing
 the Push-Sum mass weight to n_i — the v/w ratio then converges to exactly that
@@ -190,11 +201,12 @@ class SnapshotRing(NamedTuple):
 
 
 class GadgetResult(NamedTuple):
-    W: jax.Array            # (m, d) final per-node weights
-    w_consensus: jax.Array  # (d,) data-weighted network average
+    W: jax.Array            # (m, d) final per-node weights; (m, C, d) with classes
+    w_consensus: jax.Array  # (d,) data-weighted network average; (C, d)
     iters: int
     epsilon: float          # max_i ‖Δŵ_i‖ at termination
-    objective_trace: np.ndarray  # (n_checks,) primal objective of consensus w
+    objective_trace: np.ndarray  # (n_checks,) primal objective of consensus w;
+    # (n_checks, C) with classes
     time_trace: np.ndarray       # iteration index per check
     eps_trace: np.ndarray        # (n_checks,) max_i ‖Δŵ_i‖ per check
     W_avg: jax.Array | None = None  # (m, d) per-node iterate averages w̄_i
@@ -218,12 +230,14 @@ class SegmentResult(NamedTuple):
     needs to export a servable model mid-training. ``W`` stays on device
     (per-node (m, d) weights, useful for parity checks / resuming);
     ``w_consensus`` is the host-side (d,) f32 data-weighted average —
-    exactly what :class:`~repro.serve.snapshot.Snapshot` wraps."""
+    exactly what :class:`~repro.serve.snapshot.Snapshot` wraps. With
+    one-vs-rest classes W and W_sum are (m, C, d), ``w_consensus`` (C, d)
+    and ``objective`` the (C,) array of the classes' objectives."""
 
     iteration: int          # global iteration index reached (1-based count)
     W: jax.Array            # (m, d) per-node weights after the segment
     w_consensus: np.ndarray  # (d,) f32 consensus at the segment boundary
-    objective: float        # primal objective of w_consensus
+    objective: float | np.ndarray  # primal objective of w_consensus
     epsilon: float          # max_i ‖Δŵ_i‖ across the segment
     done: bool              # ε-converged or cfg.max_iters reached
     # (m, d) running iterate sum — with ``iteration`` and ``W`` this is the
@@ -255,8 +269,8 @@ class TrainState(NamedTuple):
     restores it."""
 
     iteration: int
-    W: jax.Array            # (m, d) per-node weights
-    W_sum: jax.Array        # (m, d) running sum of iterates
+    W: jax.Array            # (m, d) per-node weights; (m, C, d) with classes
+    W_sum: jax.Array        # (m, d) running sum of iterates; (m, C, d)
 
 
 # Host↔device traffic instrumentation, read by benchmarks/gossip_device_bench.py:
@@ -275,7 +289,7 @@ def reset_transfer_stats() -> None:
 def _partition_counts(y_parts: jax.Array, n_counts=None) -> jax.Array:
     """Per-node valid-row counts as f32: uniform n_i unless the caller passes
     explicit ``n_counts`` (non-uniform partitions, padded to a common n_i)."""
-    m, n_i = y_parts.shape
+    m, n_i = y_parts.shape[:2]
     if n_counts is None:
         return jnp.full((m,), float(n_i), jnp.float32)
     counts = np.asarray(n_counts, np.float32)
@@ -307,6 +321,31 @@ def _unpack_partitions(X_parts):
     X = jnp.asarray(X_parts)
     m, n_i, d = X.shape
     return X, m, n_i, d, X.dtype
+
+
+def _weight_shape(y_parts, m: int, d: int) -> tuple:
+    """(m, d) for binary (m, n_i) labels, (m, C, d) for one-vs-rest
+    (m, n_i, C) ones."""
+    return (m,) + tuple(np.shape(y_parts)[2:]) + (d,)
+
+
+def _check_classes(cfg: GadgetConfig, y_parts, *, snapshot_every=None,
+                   telemetry=None) -> None:
+    """Refuse what has no class axis yet when the labels are one-vs-rest."""
+    if np.ndim(y_parts) != 3:
+        return
+    missing = [what for what, on in (
+        ("the sequential Push-Sum rounds (fused=False)", not cfg.fused),
+        ("the snapshot ring (snapshot_every)", snapshot_every is not None),
+        ("the telemetry ring (telemetry)", telemetry is not None)) if on]
+    if missing:
+        raise ValueError("one-vs-rest labels (m, n_i, C): "
+                         + " and ".join(missing) + " have no class axis")
+
+
+def _per_node(v: jax.Array, W: jax.Array) -> jax.Array:
+    """(m,) node values shaped to broadcast over W's (m, ...) rows."""
+    return v.reshape(v.shape + (1,) * (W.ndim - 1))
 
 
 def _sparse_block_bound(cfg: GadgetConfig, X_parts, X) -> int | None:
@@ -450,20 +489,21 @@ def _gossip_step(cfg: GadgetConfig, m: int,
                                   sparse_block_bound)
     # Push-Sum: values n_i·w̃_i with mass weights n_i ⇒ weighted mean; R
     # rounds collapsed into one fused mix-and-renormalize matmul when fused.
+    # Classes mix as (m, C, d) values under the node's one mass.
     with jax.named_scope("gadget.push_sum_mix"):
         mix = mix_collapsed if cfg.fused else mix_rounds
-        vals, wts = mix(W_half * n_counts[:, None], n_counts, Bs)
+        vals, wts = mix(W_half * _per_node(n_counts, W_half), n_counts, Bs)
         mass = jnp.sum(wts) / jnp.sum(n_counts)
-        W_new = vals / wts[:, None]
+        W_new = vals / _per_node(wts, vals)
     if cfg.project_after_gossip:
         with jax.named_scope("gadget.project"):
-            W_new = jax.vmap(lambda w: obj.project_ball(w, cfg.lam))(W_new)
+            W_new = hinge_ops.project_rows(W_new, cfg.lam)
     if cfg.faults is not None and cfg.faults.dead_nodes:
         # crashed nodes neither train nor receive: their mixing row is e_d
         # (nothing reaches the others), and the bit-exact freeze of their own
         # row happens here, after the mix's renormalizing divide
         with jax.named_scope("gadget.push_sum_mix"):
-            W_new = jnp.where(flt.dead_mask(cfg.faults, m)[:, None], W, W_new)
+            W_new = jnp.where(_per_node(flt.dead_mask(cfg.faults, m), W), W, W_new)
     with jax.named_scope("gadget.average"):
         W_sum = W_sum + W_new
     if node_mass:
@@ -476,7 +516,8 @@ def _fleet_half_step(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
                      sparse_block_bound: int | None):
     """Steps (a)-(f) for all m nodes: each node's minibatch draw and gather,
     then the Pallas or jnp half-step, whose ball projection (step (f)) runs
-    under the ``gadget.project`` scope."""
+    under the ``gadget.project`` scope. One-vs-rest W (m, C, d) and y
+    (m, n_i, C) take the same calls: the drawn rows serve every class."""
     tf = t.astype(jnp.float32)
     ids = _batch_ids(data_key, t, n_counts, cfg.batch_size)
 
@@ -565,12 +606,21 @@ def _trace_closures(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
     trainer, the segment trainer and the host reference so their traces agree
     bit-for-bit. With kernels on, ELL planes go whole to the
     ``ell_objective`` kernel; otherwise ``jnp.take`` over flat views, the
-    kernel's oracle."""
+    kernel's oracle. With one-vs-rest labels (m, n_i, C) the consensus is
+    (C, d) and ``objective_of`` gives the (C,) class objectives, dense or ELL
+    (``svm_objective.class_objectives``, a scan over row blocks)."""
     total_n = jnp.sum(n_counts)
 
     def consensus_of(W):
         with jax.named_scope("gadget.consensus"):
-            return jnp.sum(W * n_counts[:, None], axis=0) / total_n
+            return jnp.sum(W * _per_node(n_counts, W), axis=0) / total_n
+
+    if y.ndim == 3:
+        def objective_of(w):
+            with jax.named_scope("gadget.objective"):
+                return obj.class_objectives(w, X, y, n_counts, cfg.lam, total_n)
+
+        return objective_of, consensus_of
 
     if isinstance(X, tuple) and cfg.use_kernels:
         def objective_of(w):
@@ -598,9 +648,10 @@ def _trace_closures(cfg: GadgetConfig, X, y: jax.Array, n_counts: jax.Array,
 
 
 def _eps_check(W: jax.Array, W_prev: jax.Array) -> jax.Array:
-    """The stopping rule's ε = max_i ‖W_i − W_prev_i‖ over one check window."""
+    """The stopping rule's ε = max_i ‖W_i − W_prev_i‖ over one check window;
+    with classes the max runs over (node, class) rows."""
     with jax.named_scope("gadget.eps_check"):
-        return jnp.max(jnp.linalg.norm(W - W_prev, axis=1))
+        return jnp.max(jnp.linalg.norm(W - W_prev, axis=-1))
 
 
 def _cache_cfg(cfg: GadgetConfig) -> GadgetConfig:
@@ -823,7 +874,7 @@ def _make_device_train(cfg: GadgetConfig, m: int, n_i: int, d: int,
             tele0 = ()
         init = (W0, W_sum0, jnp.int32(1), snaps0, tele0, jnp.int32(0),
                 jnp.float32(jnp.inf),
-                jnp.full((n_chunks,), jnp.nan, jnp.float32),
+                jnp.full((n_chunks,) + y.shape[2:], jnp.nan, jnp.float32),
                 jnp.zeros((n_chunks,), jnp.int32),
                 jnp.full((n_chunks,), jnp.nan, jnp.float32),
                 jnp.full((n_chunks,), jnp.nan, jnp.float32),
@@ -894,6 +945,7 @@ def _prepare_device_train(cfg: GadgetConfig, X_parts: jax.Array, y_parts: jax.Ar
     n_counts = _partition_counts(y_parts, n_counts)
     data_key, mix_key = _stream_keys(cfg.seed)
     sparse_block_bound = _sparse_block_bound(cfg, X_parts, X)
+    shape = _weight_shape(y_parts, m, d)
 
     if cfg.topology == "random":
         B_stack = None
@@ -919,14 +971,15 @@ def _prepare_device_train(cfg: GadgetConfig, X_parts: jax.Array, y_parts: jax.Ar
                                tele.slots if tele else 0,
                                tele.per_node if tele else False)
     args = (X, jnp.asarray(y_parts), B_stack, data_key, mix_key,
-            n_counts, jnp.zeros((m, d), dtype), jnp.zeros((m, d), dtype))
+            n_counts, jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
     return train, args
 
 
 @functools.lru_cache(maxsize=64)
 def _gossip_bytes_per_iter(topology: str, m: int, R: int, d: int) -> int:
     """Analytic gossip payload bytes one iteration moves: R rounds × live
-    off-diagonal links per round × (d weight floats + 1 mass float) × 4.
+    off-diagonal links per round × (d weight floats + 1 mass float) × 4; a
+    node's C one-vs-rest planes travel as C·d floats under its one mass.
     Deterministic topologies count their matrix cycle's mean off-diagonal
     support; the random protocol pushes to exactly one neighbor per node per
     round. Feeds the ``train.gossip_bytes`` counter."""
@@ -942,20 +995,22 @@ def _gossip_bytes_per_iter(topology: str, m: int, R: int, d: int) -> int:
 
 def _record_train_telemetry(cfg: GadgetConfig, m: int, d: int, X,
                             sparse_block_bound, n_iters: int,
-                            registry=None) -> None:
+                            registry=None, C: int = 1) -> None:
     """Registry accounting for ``n_iters`` finished training iterations.
 
     The jitted loop cannot count its own kernel launches, so the host mirrors
     what the traced program dispatches per iteration — iteration and
     gossip-byte counters always, kernel launch/bytes/FLOPs series when the
-    Pallas path is active — onto the (default) registry. Pure host-side
-    bookkeeping: it never touches the traced program or the trajectory."""
+    Pallas path is active — onto the (default) registry. ``C`` one-vs-rest
+    classes widen the gossip payload to C·d floats and the ELL half-step's
+    weight traffic C-fold. Pure host-side bookkeeping: it never touches the
+    traced program or the trajectory."""
     if n_iters <= 0:
         return
     reg = tmr.default_registry() if registry is None else registry
     reg.counter("train.iterations").inc(n_iters)
     reg.counter("train.gossip_bytes").inc(
-        n_iters * _gossip_bytes_per_iter(cfg.topology, m, cfg.gossip_rounds, d))
+        n_iters * _gossip_bytes_per_iter(cfg.topology, m, cfg.gossip_rounds, C * d))
     if not cfg.use_kernels:
         return
     B = cfg.batch_size
@@ -964,7 +1019,7 @@ def _record_train_telemetry(cfg: GadgetConfig, m: int, d: int, X,
         schedule, blk_d, n_blocks_max = hinge_ops.resolve_ell_schedule(
             cfg.sparse_schedule, B=B, k=k, d=d, n_blocks_max=sparse_block_bound)
         hinge_ops.record_launch("ell_fleet_half_step", n_iters, registry=reg,
-                                m=m, B=B, k=k, d=d, schedule=schedule,
+                                m=m, B=B, k=k, d=d, C=C, schedule=schedule,
                                 blk_d=blk_d, n_blocks_max=n_blocks_max)
     elif cfg.fused:
         hinge_ops.record_launch("fleet_half_step", n_iters, registry=reg,
@@ -986,7 +1041,9 @@ def gadget_train(
 ) -> GadgetResult:
     """Simulator-path GADGET over m nodes. X_parts: (m, n_i, d) dense, or a
     ``repro.sparse.EllPartitions`` of stacked padded-ELL planes (sparse local
-    half-steps; gossip stays dense in w). y_parts: (m, n_i).
+    half-steps; gossip stays dense in w). y_parts: (m, n_i), or (m, n_i, C)
+    ±1 one-vs-rest labels (module docstring; no snapshots or telemetry ring
+    with classes).
 
     Thin host wrapper around the jitted device loop: uploads the data and (for
     deterministic topologies) one stacked mixing-matrix cycle, runs the
@@ -1018,12 +1075,14 @@ def gadget_train(
     (asserted in tests).
     """
     _validate_topology(cfg)
+    _check_classes(cfg, y_parts, snapshot_every=snapshot_every, telemetry=telemetry)
     tele_cfg = tmt.validate_telemetry(telemetry)
 
     empty = np.zeros((0,), np.float32)
     if cfg.max_iters <= 0:  # zero-iteration call: return the initial state
         snap_every = _validate_snapshotting(snapshot_every, snapshot_slots)
         _, m, n_i, d, dtype = _unpack_partitions(X_parts)
+        shape = _weight_shape(y_parts, m, d)
         trace = None
         if tele_cfg:
             # W = 0 everywhere: disagreement is exactly 0, nothing recorded
@@ -1052,11 +1111,12 @@ def gadget_train(
                 objectives=np.full((int(snapshot_slots),), np.nan, np.float32),
                 count=0, final_w=np.zeros((d,), np.float32),
                 final_iteration=0, final_objective=1.0)
-        return GadgetResult(W=jnp.zeros((m, d), dtype),
-                            w_consensus=jnp.zeros((d,), dtype),
+        return GadgetResult(W=jnp.zeros(shape, dtype),
+                            w_consensus=jnp.zeros(shape[1:], dtype),
                             iters=0, epsilon=float("inf"),
-                            objective_trace=empty, time_trace=empty.astype(np.int32),
-                            eps_trace=empty, W_avg=jnp.zeros((m, d), dtype),
+                            objective_trace=np.zeros((0,) + shape[1:-1], np.float32),
+                            time_trace=empty.astype(np.int32),
+                            eps_trace=empty, W_avg=jnp.zeros(shape, dtype),
                             snapshots=ring, mass_trace=empty, telemetry=trace)
 
     train, args = _prepare_device_train(cfg, X_parts, y_parts, n_counts,
@@ -1091,7 +1151,8 @@ def gadget_train(
     rcfg = _resolve_kernels(cfg)
     X_in, m_in, _, d_in, _ = _unpack_partitions(X_parts)
     _record_train_telemetry(rcfg, m_in, d_in, X_in,
-                            _sparse_block_bound(rcfg, X_parts, X_in), iters)
+                            _sparse_block_bound(rcfg, X_parts, X_in), iters,
+                            C=W.shape[1] if W.ndim == 3 else 1)
     ring = None
     if snapshot_every:
         sw, si, so, sc = snaps
@@ -1226,8 +1287,8 @@ def gadget_train_stream(
     role ``cfg.check_every`` plays in ``gadget_train``); the stream ends after
     the segment where ``ε < cfg.epsilon`` or ``cfg.max_iters`` is reached
     (that last result carries ``done=True``). Accepts the same dense
-    (m, n_i, d) / ``EllPartitions`` data and ``n_counts`` conventions as
-    ``gadget_train``. The host blocks once per segment, at its boundary, then
+    (m, n_i, d) / ``EllPartitions`` data, (m, n_i) or (m, n_i, C) labels and
+    ``n_counts`` conventions as ``gadget_train``. The host blocks once per segment, at its boundary, then
     reads the segment's outputs back one array at a time (five reads, nine
     with ``telemetry``), each counted on the registry counter
     ``train.host_readbacks``.
@@ -1269,6 +1330,7 @@ def gadget_train_stream(
     pre-crash lineage.
     """
     _validate_topology(cfg)
+    _check_classes(cfg, y_parts, telemetry=telemetry)
     tele_cfg = tmt.validate_telemetry(telemetry)
     if int(segment_iters) < 1:
         raise ValueError(f"segment_iters must be >= 1, got {segment_iters}")
@@ -1295,19 +1357,23 @@ def gadget_train_stream(
     segment = _make_segment_train(_cache_cfg(cfg), m, n_i, d,
                                   int(segment_iters), sparse_block_bound,
                                   tele=tele_cfg is not None)
+    shape = _weight_shape(y, m, d)
+    n_classes = y.shape[2] if y.ndim == 3 else 1
+    # the objective read back: a float, or the classes' (C,) array
+    objective_from = float if y.ndim == 2 else np.asarray
     if resume is not None:
         W = jnp.asarray(resume.W, dtype)
         W_sum = jnp.asarray(resume.W_sum, dtype)
-        if W.shape != (m, d) or W_sum.shape != (m, d):
+        if W.shape != shape or W_sum.shape != shape:
             raise ValueError(
                 f"resume state shape {W.shape}/{W_sum.shape} does not match "
-                f"the ({m}, {d}) fleet")
+                f"the {shape} fleet")
         if int(resume.iteration) < 0:
             raise ValueError(f"resume iteration must be >= 0, got {resume.iteration}")
         t = jnp.int32(int(resume.iteration) + 1)
     else:
-        W = jnp.zeros((m, d), dtype)
-        W_sum = jnp.zeros((m, d), dtype)
+        W = jnp.zeros(shape, dtype)
+        W_sum = jnp.zeros(shape, dtype)
         t = jnp.int32(1)
     reg = trace_registry if trace_registry is not None else tmr.default_registry()
     iteration = int(resume.iteration) if resume is not None else 0
@@ -1331,7 +1397,7 @@ def gadget_train_stream(
             iteration = _readback(reg, seg_ctx, "t", t, int) - 1
             w_host = _readback(reg, seg_ctx, "w_consensus", w_cons, np.asarray)
             eps_f = _readback(reg, seg_ctx, "epsilon", eps, float)
-            objective_f = _readback(reg, seg_ctx, "objective", objective, float)
+            objective_f = _readback(reg, seg_ctx, "objective", objective, objective_from)
             mass_f = _readback(reg, seg_ctx, "mass", mass, float)
             seg_tele = None
             if tele_cfg:
@@ -1351,7 +1417,7 @@ def gadget_train_stream(
                     tmr.default_registry().counter("train.nonfinite").inc()
                     raise NonFiniteWeightsError(iteration)
                 _record_train_telemetry(cfg, m, d, X, sparse_block_bound,
-                                        iteration - prev_iteration)
+                                        iteration - prev_iteration, C=n_classes)
                 if seg_tele is not None:
                     greg = tmr.default_registry()
                     greg.gauge("train.final_disagreement").set(seg_tele.disagreement)
@@ -1362,8 +1428,8 @@ def gadget_train_stream(
                     greg.counter("train.fault_drops").inc(seg_tele.drops)
                 done = eps_f < cfg.epsilon or iteration >= cfg.max_iters
             seg_span.fields.update(
-                iteration=iteration, objective=objective_f, epsilon=eps_f,
-                done=done,
+                iteration=iteration, objective=float(np.max(objective_f)),
+                epsilon=eps_f, done=done, n_classes=n_classes,
                 resumed_from_trace=trace_link if first_segment else None)
         first_segment = False
         yield SegmentResult(iteration=iteration, W=W, w_consensus=w_host,
@@ -1442,6 +1508,9 @@ def gadget_train_reference(
     """
     X, m, n_i, d, dtype = _unpack_partitions(X_parts)
     _validate_topology(cfg)
+    if np.ndim(y_parts) == 3:
+        raise ValueError("one-vs-rest labels (m, n_i, C): gadget_train_reference "
+                         "runs the sequential rounds, which have no class axis")
     cfg = _resolve_kernels(cfg)._replace(fused=False)
     cfg = _resolve_faults(cfg, m)
     n_counts = _partition_counts(y_parts, n_counts)

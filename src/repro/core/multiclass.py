@@ -3,9 +3,10 @@ variants of SVMs").
 
 One-vs-rest over the binary GADGET solver: class c gets its own weight
 vector trained on (x, +1 if y==c else -1); prediction is argmax_c <w_c, x>.
-All classes train in ONE run — the per-node weight matrix W (m, C, d) rides
-through the same local Pegasos half-step and Push-Sum rounds (Push-Vector
-over the stacked class dimension), so gossip cost is shared across classes.
+All classes train in ONE run of the normal device path: ``gadget_train``
+takes the (m, n_i, C) ±1 label plane and carries W as (m, C, d) through the
+same half-step, Push-Sum mix and ε-check (``repro.core.gadget`` module
+docstring), so gossip cost is shared across classes.
 
 Prediction dispatches the serving-side fused scores+argmax kernel
 (``hinge_subgrad.ops.dense_predict`` — one launch for margins AND argmax),
@@ -19,10 +20,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.gadget import GadgetConfig
-from repro.core.push_sum import PushSumSim
+from repro.core.gadget import GadgetConfig, gadget_train
 from repro.kernels.hinge_subgrad import ops as hinge_ops
 
 __all__ = ["MulticlassResult", "gadget_train_multiclass", "predict_multiclass"]
@@ -34,71 +33,13 @@ class MulticlassResult(NamedTuple):
     iters: int
 
 
-def _half_step_all_classes(W, Xi, yi, ids, lam, t, project):
-    """W: (C, d); one shared minibatch drives every class's binary problem."""
-    Xb = Xi[ids]                       # (B, d)
-    yb = yi[ids]                       # (B,) integer labels
-    C = W.shape[0]
-    y_bin = jnp.where(yb[None, :] == jnp.arange(C)[:, None], 1.0, -1.0)  # (C, B)
-    margins = y_bin * (Xb @ W.T).T     # (C, B)
-    viol = (margins < 1.0).astype(Xb.dtype)
-    L = jnp.einsum("cb,bd->cd", viol * y_bin, Xb) / Xb.shape[0]
-    alpha = 1.0 / (lam * t)
-    W_half = (1.0 - lam * alpha) * W + alpha * L
-    if project:
-        norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
-        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
-        W_half = W_half * scale
-    return W_half
-
-
 def gadget_train_multiclass(X_parts: jax.Array, y_parts: jax.Array, n_classes: int,
                             cfg: GadgetConfig = GadgetConfig()) -> MulticlassResult:
-    """X_parts: (m, n_i, d); y_parts: (m, n_i) int labels in [0, C)."""
-    m, n_i, d = X_parts.shape
-    C = n_classes
-    sim = PushSumSim(m, cfg.topology, seed=cfg.seed)
-    key = jax.random.PRNGKey(cfg.seed)
-
-    @jax.jit
-    def chunk(W, t0, B_stack, key0):
-        def step(carry, inp):
-            W, t = carry
-            Bs, k = inp
-            tf = t.astype(jnp.float32)
-            keys = jax.random.split(k, m)
-            ids = jax.vmap(lambda kk: jax.random.randint(kk, (cfg.batch_size,), 0, n_i))(keys)
-            W_half = jax.vmap(
-                lambda w, Xi, yi, ii: _half_step_all_classes(
-                    w, Xi, yi, ii, cfg.lam, tf, cfg.project_before_gossip)
-            )(W, X_parts, y_parts, ids)
-            flat = W_half.reshape(m, C * d)
-            for r in range(cfg.gossip_rounds):
-                flat = Bs[r].T @ flat
-            W_new = flat.reshape(m, C, d)
-            return (W_new, t + 1), None
-
-        keys = jax.random.split(key0, B_stack.shape[0])
-        (W, t0), _ = jax.lax.scan(step, (W, t0), (B_stack, keys))
-        return W, t0
-
-    W = jnp.zeros((m, C, d), X_parts.dtype)
-    t = jnp.int32(1)
-    it = 0
-    while it < cfg.max_iters:
-        n = min(cfg.check_every, cfg.max_iters - it)
-        B_stack = np.stack([
-            np.stack([sim.matrix(it + s * cfg.gossip_rounds + r)
-                      for r in range(cfg.gossip_rounds)])
-            for s in range(n)]).astype(np.float32)
-        key, sub = jax.random.split(key)
-        W_prev = W
-        W, t = chunk(W, t, jnp.asarray(B_stack), sub)
-        it += n
-        eps = float(jnp.max(jnp.linalg.norm((W - W_prev).reshape(m, -1), axis=1)))
-        if eps < cfg.epsilon:
-            break
-    return MulticlassResult(W=W, w_consensus=jnp.mean(W, axis=0), iters=it)
+    """X_parts: (m, n_i, d); y_parts: (m, n_i) int labels in [0, C), turned
+    into the (m, n_i, C) ±1 one-vs-rest plane ``gadget_train`` takes."""
+    y = jnp.asarray(y_parts)[..., None] == jnp.arange(n_classes)
+    res = gadget_train(X_parts, jnp.where(y, 1.0, -1.0).astype(jnp.float32), cfg)
+    return MulticlassResult(W=res.W, w_consensus=res.w_consensus, iters=res.iters)
 
 
 def predict_multiclass(w_consensus: jax.Array, X: jax.Array, *,

@@ -133,8 +133,8 @@ def mix_rounds(values: jax.Array, weight: jax.Array, B_rounds: jax.Array):
 
     def body(carry, B):
         v, w = carry
-        return (jnp.matmul(B.T, v, precision=F32),
-                jnp.matmul(B.T, w, precision=F32)), None
+        return (jnp.tensordot(B.T, v, axes=1, precision=F32),
+                jnp.tensordot(B.T, w, axes=1, precision=F32)), None
 
     (v, w), _ = jax.lax.scan(body, (values, weight), B_rounds)
     return v, w
@@ -163,9 +163,10 @@ def mix_collapsed(values: jax.Array, weight: jax.Array, P: jax.Array):
     """Apply a collapsed round product to (n, ...) values and (n,) mass
     weights: one matmul per tensor, replacing the R-round ``mix_rounds`` scan.
     ``P`` comes from :func:`collapse_rounds` or a precomputed
-    ``topology.build_product_stack`` slice."""
-    return (jnp.matmul(P, values, precision=F32),
-            jnp.matmul(P, weight, precision=F32))
+    ``topology.build_product_stack`` slice. Both mixes contract the node axis
+    alone, so (n, C, d) one-vs-rest values keep their layout."""
+    return (jnp.tensordot(P, values, axes=1, precision=F32),
+            jnp.tensordot(P, weight, axes=1, precision=F32))
 
 
 # ---------------------------------------------------------------------------

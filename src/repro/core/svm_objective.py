@@ -8,6 +8,8 @@ Objective (paper Eq. 1):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -20,6 +22,7 @@ __all__ = [
     "primal_objective",
     "primal_objective_masked",
     "primal_objective_masked_ell",
+    "class_objectives",
     "hinge_subgradient",
     "pegasos_update",
     "project_ball",
@@ -61,6 +64,45 @@ def primal_objective_masked_ell(w: jax.Array, cols: jax.Array, vals: jax.Array,
     margins = y * jnp.sum(vals * jnp.take(w, cols, axis=0), axis=-1)
     hinge = jnp.sum(jnp.where(valid, jnp.maximum(0.0, 1.0 - margins), 0.0)) / total
     return 0.5 * lam * jnp.dot(w, w) + hinge
+
+
+# Rows of each node per step of ``class_objectives``' scan over row blocks.
+CLASS_OBJECTIVE_ROWS = 512
+
+
+def class_objectives(W: jax.Array, X, y: jax.Array, n_counts: jax.Array,
+                     lam: float, total: jax.Array) -> jax.Array:
+    """(C,) one-vs-rest primal objectives of the class planes W (C, d): for
+    each class, ½λ‖w_c‖² + Σ hinge(y_c · ⟨w_c, x⟩) / total over the first
+    n_counts[i] rows of every node.
+
+    X: dense (m, n_i, d) partitions or the (cols, vals) (m, n_i, k) ELL
+    planes; y: (m, n_i, C) ±1. The pass scans row blocks of
+    ``CLASS_OBJECTIVE_ROWS`` a node: an ELL block gathers rows of Wᵀ (d, C)
+    for its slots, so no (rows, k, C) array of the whole split is made. The
+    last block is taken from the end and masks the rows its predecessor
+    counted. Each block's hinge sums are added up at the end, as one
+    reduction over the blocks rather than a running sum."""
+    m, n_i = y.shape[:2]
+    rows = min(CLASS_OBJECTIVE_ROWS, n_i)
+    wt = W.T
+
+    def block(b):
+        start = jnp.minimum(b * rows, n_i - rows)
+        take = functools.partial(jax.lax.dynamic_slice_in_dim, start_index=start,
+                                 slice_size=rows, axis=1)
+        if isinstance(X, tuple):
+            cols, vals = take(X[0]), take(X[1])
+            s = jnp.sum(vals[..., None] * wt[cols], axis=2)
+        else:
+            s = jnp.einsum("mrd,dc->mrc", take(X), wt, precision=F32)
+        row = start + jnp.arange(rows)
+        ok = (row >= b * rows)[None, :] & (row[None, :] < n_counts[:, None])
+        hinge = jnp.where(ok[..., None], jnp.maximum(0.0, 1.0 - take(y) * s), 0.0)
+        return jnp.sum(hinge, axis=(0, 1))
+
+    hinge = jnp.sum(jax.lax.map(block, jnp.arange(-(-n_i // rows))), axis=0)
+    return 0.5 * lam * jnp.sum(W * W, axis=1) + hinge / total
 
 
 def hinge_subgradient(w: jax.Array, X: jax.Array, y: jax.Array) -> jax.Array:

@@ -64,7 +64,9 @@ def launch_cost(kind: str, *, m: int = 1, B: int = 0, d: int = 0, k: int = 0,
     ``fleet_half_step`` (one fused launch, or the 2m-launch vmapped fallback
     above ``FLEET_TILE_BUDGET_BYTES`` — the model applies the same cutover),
     ``ell_fleet_half_step`` (two launches; prefetch visits
-    ``m·n_blocks_max`` w blocks, sweep visits every block),
+    ``m·n_blocks_max`` w blocks, sweep visits every block; with ``C``
+    one-vs-rest classes each block holds C rows and every weight counts C
+    times),
     ``dense_predict`` / ``ell_predict`` (one fused launch each).
     """
     if kind == "local_half_step":
@@ -86,8 +88,8 @@ def launch_cost(kind: str, *, m: int = 1, B: int = 0, d: int = 0, k: int = 0,
             n_d_blocks = -(-d // max(blk_d, 1))
             blocks = m * n_d_blocks
             w_bytes = 12 * m * n_d_blocks * max(blk_d, 1)
-        return {"launches": 2, "bytes": entry_bytes + w_bytes,
-                "flops": m * (4 * B * k + 2 * d), "blocks_visited": blocks}
+        return {"launches": 2, "bytes": entry_bytes + C * w_bytes,
+                "flops": C * m * (4 * B * k + 2 * d), "blocks_visited": blocks}
     if kind == "dense_predict":
         return {"launches": 1, "bytes": 4 * (B * d + C * d + B * C + B),
                 "flops": 2 * B * C * d}
@@ -141,6 +143,16 @@ def _project_ball(w: jax.Array, lam: float) -> jax.Array:
     norm = jnp.linalg.norm(w)
     scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norm, 1e-30))
     return w * scale
+
+
+def project_rows(W: jax.Array, lam: float) -> jax.Array:
+    """Each row (last axis) of an (m, d) or (m, C, d) plane onto the ball.
+    Rows are mapped in place: a reshape of a class plane to (m·C, d) rows
+    would relay it out in device memory, C not being a sublane multiple."""
+    project = functools.partial(_project_ball, lam=lam)
+    for _ in range(W.ndim - 1):
+        project = jax.vmap(project)
+    return project(W)
 
 
 def padded_row_mask(n_padded: int, n_valid: int) -> jax.Array:
@@ -217,7 +229,15 @@ def fleet_half_step(W: jax.Array, X: jax.Array, y: jax.Array, *, lam: float,
     inside the device-resident gossip loop. Tiles larger than
     ``FLEET_TILE_BUDGET_BYTES`` fall back to the blocked two-kernel path,
     which never needs the whole tile resident.
+
+    One-vs-rest: W (m, C, d) and y (m, B, C) run the kernel once per class
+    on the same tiles (a class grid axis, by ``vmap``).
     """
+    if W.ndim == 3:
+        return jax.vmap(
+            lambda Wc, yc: fleet_half_step(Wc, X, yc, lam=lam, t=t, project=project,
+                                           interpret=interpret),
+            in_axes=(1, 2), out_axes=1)(W, y)
     m, B, d = X.shape
     _maybe_record("fleet_half_step", X, m=m, B=B, d=d)
     if interpret is None:
@@ -242,7 +262,7 @@ def fleet_half_step(W: jax.Array, X: jax.Array, y: jax.Array, *, lam: float,
     W_half = K.fleet_half_step(Xp, Wp, yp, mask, scal, interpret=interpret)[:, :d]
     if project:
         with jax.named_scope("gadget.project"):
-            W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
+            W_half = project_rows(W_half, lam)
     return W_half.astype(W.dtype)
 
 
@@ -342,6 +362,11 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
     kernel launches (gather-dot margins, scatter-add grad) touching O(B·k)
     feature bytes instead of O(B·d).
 
+    One-vs-rest: W (m, C, d) and y (m, B, C) ±1. The same two launches take
+    every class: a weight block is (C_pad, blk_d), so one gather of a row's
+    columns serves all C classes (``sparse`` module docstring); each (node,
+    class) row is projected on its own.
+
     ``schedule`` selects how the kernels walk w's d-blocks:
 
     * ``"sweep"`` — the data-oblivious grid (m, d/blk_d): every node visits
@@ -364,7 +389,8 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
     pads are inert under the ELL convention.
     """
     m, B, k = cols.shape
-    d = W.shape[1]
+    d = W.shape[-1]
+    C = W.shape[1] if W.ndim == 3 else 1
     if k == 0:  # k_max=0 planes (e.g. all rows empty after bucketing): widen
         cols = jnp.zeros((m, B, 1), jnp.int32)  # to one inert (0, 0) entry so
         vals = jnp.zeros((m, B, 1), jnp.float32)  # block shapes stay nonzero
@@ -373,12 +399,19 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         interpret = default_interpret()
     schedule, blk_d, n_blocks_max = resolve_ell_schedule(
         schedule, B=B, k=k, d=d, n_blocks_max=n_blocks_max, blk_d=blk_d)
-    _maybe_record("ell_fleet_half_step", vals, m=m, B=B, k=k, d=d,
+    _maybe_record("ell_fleet_half_step", vals, m=m, B=B, k=k, d=d, C=C,
                   schedule=schedule, blk_d=blk_d, n_blocks_max=n_blocks_max)
 
     colsP = _pad_to(_pad_to(cols.astype(jnp.int32), 8, 1), 128, 2)
     valsP = _pad_to(_pad_to(vals.astype(jnp.float32), 8, 1), 128, 2)
     yp = _pad_to(y.astype(jnp.float32), 8, 1)
+
+    def pad_plane(width):
+        """W zero-padded to ``width`` columns, and its classes to C_pad rows."""
+        widths = [(0, 0)] * (W.ndim - 1) + [(0, width - d)]
+        if W.ndim == 3:
+            widths[1] = (0, S.class_pad(C) - C)
+        return jnp.pad(W.astype(jnp.float32), widths)
 
     tf = jnp.asarray(t, jnp.float32)
     alpha = 1.0 / (lam * tf)
@@ -390,7 +423,7 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
         bids = ell_block_map(colsP, valsP, blk_d=blk_d, n_d_blocks=n_d_blocks,
                              n_blocks_max=n_blocks_max)
         # one extra zero block after the last real one: the sentinel's DMA pad
-        Wp = _pad_to(W.astype(jnp.float32), (n_d_blocks + 1) * blk_d, 1)
+        Wp = pad_plane((n_d_blocks + 1) * blk_d)
         margins = S.ell_margins_prefetch(colsP, valsP, Wp, yp, bids,
                                          blk_d=blk_d, n_d_blocks=n_d_blocks,
                                          n_rows=B, interpret=interpret)
@@ -400,25 +433,47 @@ def ell_fleet_half_step(W: jax.Array, cols: jax.Array, vals: jax.Array,
                                        interpret=interpret)
         # fold buckets into the axpy: decay everywhere, scatter-add the live
         # buckets (sentinel buckets index past d_pad → dropped, and are zero)
-        flat = (bids[:, :, None] * blk_d
-                + jnp.arange(blk_d, dtype=jnp.int32)[None, None, :]).reshape(m, -1)
-        W_half = jax.vmap(
-            lambda w_row, g, fi: ((1.0 - scal[0]) * w_row)
-            .at[fi].add(scal[1] * g, mode="drop")
-        )(Wp[:, :d_pad], G.reshape(m, -1), flat)[:, :d]
+        with jax.named_scope("gadget.decay"):
+            if W.ndim == 3:
+                W_half = _decay_scatter_blocks(Wp, G, bids, scal)[:, :C, :d]
+            else:
+                flat = (bids[:, :, None] * blk_d
+                        + jnp.arange(blk_d, dtype=jnp.int32)[None, None, :]).reshape(m, -1)
+                W_half = jax.vmap(
+                    lambda w_row, g, fi: ((1.0 - scal[0]) * w_row)
+                    .at[fi].add(scal[1] * g, mode="drop")
+                )(Wp[:, :d_pad], G.reshape(m, -1), flat)[:, :d]
     else:
-        Wp = _pad_to(W.astype(jnp.float32), blk_d, 1)
+        Wp = pad_plane(-(-d // blk_d) * blk_d)
         margins = S.ell_margins(colsP, valsP, Wp, yp, blk_d=blk_d, n_rows=B,
                                 interpret=interpret)
         # pad rows carry y=0 ⇒ coefficient 0 (padded_row_mask invariant):
         # inert in the scatter though their margin 0 selects as a violator
         coeff = jnp.where(margins < 1.0, yp, 0.0)
         W_half = S.ell_grad_update(colsP, valsP, Wp, coeff, scal, blk_d=blk_d,
-                                   n_rows=B, interpret=interpret)[:, :d]
+                                   n_rows=B, interpret=interpret)
+        W_half = W_half[:, :C, :d] if W.ndim == 3 else W_half[:, :d]
     if project:
         with jax.named_scope("gadget.project"):
-            W_half = jax.vmap(lambda w: _project_ball(w, lam))(W_half)
+            W_half = project_rows(W_half, lam)
     return W_half.astype(W.dtype)
+
+
+def _decay_scatter_blocks(Wp: jax.Array, G: jax.Array, bids: jax.Array,
+                          scal: jax.Array) -> jax.Array:
+    """The class plane's Pegasos axpy: (1 − λα)·W everywhere, plus α/B times
+    each bucket added to its whole d-block. Wp: (m, C_pad, (n_d_blocks + 1)·
+    blk_d), the padded plane the kernels read; G: (m, n_blocks_max, C_pad,
+    blk_d) buckets; bids: (m, n_blocks_max) block ids (a sentinel's zero
+    bucket lands in the pad block). One scatter index per touched block, each
+    adding a (C_pad, blk_d) window: an index per weight, as the binary path
+    scatters, would update one strided column of C rows per index."""
+    m, c_pad, width = Wp.shape
+    blk_d = G.shape[-1]
+    blocks = Wp.reshape(m, c_pad, width // blk_d, blk_d)
+    return jax.vmap(
+        lambda w, g, b: ((1.0 - scal[0]) * w).at[:, b].add(scal[1] * g)
+    )(blocks, jnp.swapaxes(G, 1, 2), bids).reshape(m, c_pad, width)
 
 
 def ell_objective(w: jax.Array, cols: jax.Array, vals: jax.Array,

@@ -38,19 +38,30 @@ def fleet_half_step_ref(W: jax.Array, X: jax.Array, y: jax.Array, lam: float,
     once. X: (m, B, d) minibatch tiles, W: (m, d), y: (m, B) with padded rows
     carrying y=0. Same per-node math as half_step_ref, batched over the node
     axis — this is also the fused jnp path GADGET uses where the Pallas
-    kernels would only interpret (CPU)."""
+    kernels would only interpret (CPU).
+
+    One-vs-rest: W (m, C, d) and y (m, B, C) ±1 run every class's half-step
+    on the node's same rows, each (node, class) row projected on its own."""
     B = X.shape[1]
-    margins = y * jnp.einsum("mbd,md->mb", X, W, precision=F32)
-    coeff = jnp.where(margins < 1.0, y, 0.0)
-    L = jnp.einsum("mb,mbd->md", coeff, X, precision=F32) / B
+    if W.ndim == 3:
+        margins = y * jnp.einsum("mbd,mcd->mbc", X, W, precision=F32)
+        coeff = jnp.where(margins < 1.0, y, 0.0)
+        L = jnp.einsum("mbc,mbd->mcd", coeff, X, precision=F32) / B
+    else:
+        margins = y * jnp.einsum("mbd,md->mb", X, W, precision=F32)
+        coeff = jnp.where(margins < 1.0, y, 0.0)
+        L = jnp.einsum("mb,mbd->md", coeff, X, precision=F32) / B
     alpha = 1.0 / (lam * t)
     W_half = (1.0 - lam * alpha) * W + alpha * L
-    if project:
-        with jax.named_scope("gadget.project"):
-            norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
-            scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
-            W_half = W_half * scale
-    return W_half
+    return _project_rows(W_half, lam) if project else W_half
+
+
+def _project_rows(W: jax.Array, lam: float) -> jax.Array:
+    """Each row (last axis) of W onto the 1/sqrt(lam) ball."""
+    with jax.named_scope("gadget.project"):
+        norms = jnp.linalg.norm(W, axis=-1, keepdims=True)
+        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
+        return W * scale
 
 
 # --------------------------------------------------------------------- sparse
@@ -78,25 +89,34 @@ def ell_fleet_half_step_ref(W: jax.Array, cols: jax.Array, vals: jax.Array,
     y: (m, B). Margins are a gather-dot against each node's resident w; the
     subgradient is a scatter-add of the violator-weighted values — same math
     as fleet_half_step_ref with X = dense(cols, vals). Also the fused jnp path
-    GADGET's sparse mode uses where Pallas would only interpret (CPU)."""
+    GADGET's sparse mode uses where Pallas would only interpret (CPU).
+
+    One-vs-rest: W (m, C, d) and y (m, B, C) ±1; one gather of each row's
+    columns serves every class, each (node, class) row projected on its own."""
     B = cols.shape[1]
-    d = W.shape[1]
-    margins = y * jax.vmap(
-        lambda w, c, v: jnp.sum(v * jnp.take(w, c, axis=0), axis=-1)
-    )(W, cols, vals)
-    coeff = jnp.where(margins < 1.0, y, 0.0)
-    L = jax.vmap(
-        lambda c, v, cf: jnp.zeros(d, jnp.float32)
-        .at[c.reshape(-1)].add((cf[:, None] * v).reshape(-1))
-    )(cols, vals, coeff) / B
+    d = W.shape[-1]
+    if W.ndim == 3:
+        margins = y * jax.vmap(
+            lambda w, c, v: jnp.sum(v[:, None, :] * jnp.take(w, c, axis=1)
+                                    .transpose(1, 0, 2), axis=-1)
+        )(W, cols, vals)
+        coeff = jnp.where(margins < 1.0, y, 0.0)
+        L = jax.vmap(
+            lambda c, v, cf: jnp.zeros((cf.shape[1], d), jnp.float32)
+            .at[:, c.reshape(-1)].add((cf.T[:, :, None] * v[None]).reshape(cf.shape[1], -1))
+        )(cols, vals, coeff) / B
+    else:
+        margins = y * jax.vmap(
+            lambda w, c, v: jnp.sum(v * jnp.take(w, c, axis=0), axis=-1)
+        )(W, cols, vals)
+        coeff = jnp.where(margins < 1.0, y, 0.0)
+        L = jax.vmap(
+            lambda c, v, cf: jnp.zeros(d, jnp.float32)
+            .at[c.reshape(-1)].add((cf[:, None] * v).reshape(-1))
+        )(cols, vals, coeff) / B
     alpha = 1.0 / (lam * t)
     W_half = (1.0 - lam * alpha) * W + alpha * L
-    if project:
-        with jax.named_scope("gadget.project"):
-            norms = jnp.linalg.norm(W_half, axis=1, keepdims=True)
-            scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / jnp.maximum(norms, 1e-30))
-            W_half = W_half * scale
-    return W_half
+    return _project_rows(W_half, lam) if project else W_half
 
 
 # -------------------------------------------------------------------- predict

@@ -29,7 +29,16 @@ real (unpadded) rows only; padded rows are inert.
 
 Layout: w travels as (m, 1, d) rows whose leading axis the grid walks, so
 every block's last two dims are (8, 128) multiples or the array's own
-extents, as Mosaic requires. Both contractions run at
+extents, as Mosaic requires.
+
+One-vs-rest classes ride in the same ``pallas_call``s: W is (m, C_pad, d),
+C_pad = C rounded up to a sublane multiple (``class_pad``; 1 stays 1, so a
+single class traces the binary shapes), and a program's weight block is
+(1, C_pad, blk_d). One one-hot per row then serves every class: the gather
+is (C_pad, blk_d) @ (blk_d, k), C_pad rows of the MXU's M dimension where
+the binary kernel fills one, written to rows [b·C_pad, (b + 1)·C_pad) of a
+(m, B·C_pad, k) output; the scatter is (C_pad, k) @ (k, blk_d) from the
+matching rows of the (m, B·C_pad, k) class coefficients. Both contractions run at
 ``Precision.HIGHEST``: the one-hot operand is exact in any precision, and
 f32 weights then come through unrounded on the chip.
 
@@ -82,7 +91,7 @@ import jax.experimental.pallas.tpu as pltpu
 
 __all__ = ["ell_margins", "ell_grad_update", "ell_margins_prefetch",
            "ell_grad_update_prefetch", "ell_objective", "onehot_t",
-           "contract_last", "DEFAULT_BLK_D_SPARSE"]
+           "contract_last", "class_pad", "DEFAULT_BLK_D_SPARSE"]
 
 DEFAULT_BLK_D_SPARSE = 512
 
@@ -112,11 +121,42 @@ def contract_last(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def class_pad(C: int) -> int:
+    """Class rows a weight block holds: C rounded up to a sublane multiple,
+    except that one class stays one row (the binary kernels' shapes)."""
+    return C if C == 1 else -(-C // 8) * 8
+
+
+def _class_rows(b, c_pad: int):
+    """Row b's slice of the (B·C_pad, k) gathered / coefficient planes."""
+    if c_pad == 1:
+        return pl.ds(b, 1)
+    return pl.ds(pl.multiple_of(b * c_pad, 8), c_pad)
+
+
+def _as_class_plane(W):
+    """(m, d) binary weights as (m, 1, d); (m, C_pad, d) as they are."""
+    return W.reshape(W.shape[0], -1, W.shape[-1])
+
+
+def _class_contrib(coeff, vals):
+    """The scatter's (m, B·C_pad, k) rows coeff·vals: (m, B) binary
+    coefficients give (m, B, k); (m, B, C) class ones C_pad rows a row."""
+    if coeff.ndim == 2:
+        return coeff[:, :, None] * vals
+    m, B, C = coeff.shape
+    cp = jnp.pad(coeff, ((0, 0), (0, 0), (0, class_pad(C) - C)))
+    return (cp[..., None] * vals[:, :, None, :]).reshape(m, B * class_pad(C), -1)
+
+
 def _gather_rows(cols_ref, w, base, g_ref, *, blk_d, n_rows):
-    """g[b] += w_blk[cols[b] - base] for the first ``n_rows`` rows."""
+    """g[b] += w_blk[:, cols[b] - base] for the first ``n_rows`` rows; w is
+    the (C_pad, blk_d) block, every class gathered by one one-hot."""
+    c_pad = w.shape[0]
+
     def row(b, carry):
         oh = onehot_t(cols_ref[0, pl.ds(b, 1), :], base, blk_d)
-        g_ref[0, pl.ds(b, 1), :] += jnp.dot(
+        g_ref[0, _class_rows(b, c_pad), :] += jnp.dot(
             w, oh, precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         return carry
@@ -124,19 +164,24 @@ def _gather_rows(cols_ref, w, base, g_ref, *, blk_d, n_rows):
     jax.lax.fori_loop(0, n_rows, row, 0)
 
 
-def _scatter_rows(cols_ref, contrib_ref, base, *, blk_d, n_rows):
-    """(1, blk_d) Σ_b Σ_k contrib[b, k] over the entries in the d-block."""
+def _scatter_rows(cols_ref, contrib_ref, base, *, blk_d, n_rows, c_pad):
+    """(C_pad, blk_d) Σ_b Σ_k contrib[b, c, k] over the entries in the d-block."""
     def row(b, acc):
         oh = onehot_t(cols_ref[0, pl.ds(b, 1), :], base, blk_d)
-        return acc + contract_last(contrib_ref[0, pl.ds(b, 1), :], oh)
+        return acc + contract_last(contrib_ref[0, _class_rows(b, c_pad), :], oh)
 
     return jax.lax.fori_loop(0, n_rows, row,
-                             jnp.zeros((1, blk_d), jnp.float32))
+                             jnp.zeros((c_pad, blk_d), jnp.float32))
 
 
 def _margins_from_gathered(gathered, vals, y):
-    """y · Σ_k vals·w[cols] from the kernel's (m, B, k) gathered weights."""
-    return y * jnp.sum(vals * gathered, axis=-1)
+    """y · Σ_k vals·w[cols] from the kernel's gathered weights: (m, B, k)
+    for (m, B) binary labels, (m, B·C_pad, k) for (m, B, C) class labels."""
+    if y.ndim == 2:
+        return y * jnp.sum(vals * gathered, axis=-1)
+    m, B, C = y.shape
+    g = gathered.reshape(m, B, -1, gathered.shape[-1])[:, :, :C]
+    return y * jnp.sum(vals[:, :, None, :] * g, axis=-1)
 
 
 def _ell_gather_kernel(cols_ref, w_ref, g_ref, *, blk_d, n_rows):
@@ -154,10 +199,12 @@ def ell_margins(cols: jax.Array, vals: jax.Array, W: jax.Array, y: jax.Array, *,
                 blk_d: int = DEFAULT_BLK_D_SPARSE, n_rows: int | None = None,
                 interpret: bool = False) -> jax.Array:
     """y * (X @ w) per node over ELL planes. cols/vals: (m, B, k) int32/f32,
-    W: (m, d), y: (m, B) → (m, B) margins. d must be a blk_d multiple;
-    rows past ``n_rows`` (default: all) are padding and score 0."""
+    W: (m, d), y: (m, B) → (m, B) margins; or W (m, C_pad, d), y (m, B, C)
+    → (m, B, C). d must be a blk_d multiple; rows past ``n_rows`` (default:
+    all) are padding and score 0."""
     m, B, k = cols.shape
-    d = W.shape[1]
+    W3 = _as_class_plane(W)
+    c_pad, d = W3.shape[1:]
     assert d % blk_d == 0, "wrapper must pad d"
     kern = functools.partial(_ell_gather_kernel, blk_d=blk_d,
                              n_rows=B if n_rows is None else n_rows)
@@ -167,14 +214,14 @@ def ell_margins(cols: jax.Array, vals: jax.Array, W: jax.Array, y: jax.Array, *,
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, c_pad, blk_d), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, B, k), jnp.float32),
+        out_specs=pl.BlockSpec((1, B * c_pad, k), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, B * c_pad, k), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cols, W.reshape(m, 1, d))
+    )(cols, W3)
     return _margins_from_gathered(gathered, vals, y)
 
 
@@ -182,7 +229,7 @@ def _ell_grad_kernel(cols_ref, contrib_ref, w_ref, scal_ref, o_ref, *, blk_d,
                      n_rows):
     j = pl.program_id(1)
     g = _scatter_rows(cols_ref, contrib_ref, j * blk_d, blk_d=blk_d,
-                      n_rows=n_rows)
+                      n_rows=n_rows, c_pad=w_ref.shape[1])
     o_ref[0] = (1.0 - scal_ref[0]) * w_ref[0] + scal_ref[1] * g
 
 
@@ -192,10 +239,12 @@ def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
                     n_rows: int | None = None,
                     interpret: bool = False) -> jax.Array:
     """W_half = (1 - scal[0]) W + scal[1] * scatter(coeff · vals → cols), per
-    node. coeff: (m, B) = 1[margin<1]·y; scal: (2,) = [lam·alpha, alpha/B] in
-    SMEM. Each (node, d-block) program writes its own output slice."""
+    node. coeff: (m, B) = 1[margin<1]·y with W (m, d), or (m, B, C) with W
+    (m, C_pad, d); scal: (2,) = [lam·alpha, alpha/B] in SMEM. Each (node,
+    d-block) program writes its own output slice."""
     m, B, k = cols.shape
-    d = W.shape[1]
+    W3 = _as_class_plane(W)
+    c_pad, d = W3.shape[1:]
     assert d % blk_d == 0, "wrapper must pad d"
     kern = functools.partial(_ell_grad_kernel, blk_d=blk_d,
                              n_rows=B if n_rows is None else n_rows)
@@ -205,17 +254,17 @@ def ell_grad_update(cols: jax.Array, vals: jax.Array, W: jax.Array,
         grid=(m, d // blk_d),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, B, k), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, B * c_pad, k), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, c_pad, blk_d), lambda i, j: (i, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, blk_d), lambda i, j: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((m, 1, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, c_pad, blk_d), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((m, c_pad, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cols, coeff[:, :, None] * vals, W.reshape(m, 1, d), scal)
-    return out.reshape(m, d)
+    )(cols, _class_contrib(coeff, vals), W3, scal)
+    return out.reshape(W.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -249,9 +298,11 @@ def ell_margins_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
     ascending, then the sentinel ``n_d_blocks``), scalar-prefetched so the
     w ``index_map`` DMAs exactly the one live block each program contracts
     against. W must carry the sentinel's landing pad: shape
-    (m, (n_d_blocks + 1)·blk_d) with the last block all-zero."""
+    (m, (n_d_blocks + 1)·blk_d), or (m, C_pad, …) with classes, with the
+    last block all-zero."""
     m, B, k = cols.shape
-    d = W.shape[1]
+    W3 = _as_class_plane(W)
+    c_pad, d = W3.shape[1:]
     assert d == (n_d_blocks + 1) * blk_d, "caller pads W + zero block"
     n_blocks_max = block_ids.shape[1]
     kern = functools.partial(_ell_gather_prefetch_kernel, blk_d=blk_d,
@@ -262,19 +313,19 @@ def ell_margins_prefetch(cols: jax.Array, vals: jax.Array, W: jax.Array,
         grid=(m, n_blocks_max),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_d), lambda i, j, b: (i, 0, b[i, j])),
+            pl.BlockSpec((1, c_pad, blk_d), lambda i, j, b: (i, 0, b[i, j])),
         ],
-        out_specs=pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, B * c_pad, k), lambda i, j, b: (i, 0, 0)),
     )
     gathered = pl.pallas_call(
         kern,
         name="ell_fleet_half_step_gather",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, B, k), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, B * c_pad, k), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_ids, cols, W.reshape(m, 1, d))
+    )(block_ids, cols, W3)
     return _margins_from_gathered(gathered, vals, y)
 
 
@@ -287,7 +338,8 @@ def _ell_grad_prefetch_kernel(bids_ref, cols_ref, contrib_ref, g_ref, *,
     @pl.when(bid < n_d_blocks)
     def _():
         g_ref[0, 0] = _scatter_rows(cols_ref, contrib_ref, bid * blk_d,
-                                    blk_d=blk_d, n_rows=n_rows)
+                                    blk_d=blk_d, n_rows=n_rows,
+                                    c_pad=g_ref.shape[2])
 
 
 def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
@@ -299,12 +351,15 @@ def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
 
     Returns the raw per-bucket scatter-adds g — (m, n_blocks_max, blk_d),
     bucket j of node i holding Σ_b coeff_b · vals[b, :] over the entries in
-    d-block ``block_ids[i, j]`` (sentinel buckets are zeros). Unlike the sweep
-    kernel it neither reads w nor applies the Pegasos axpy: untouched blocks
-    still need the (1 − λα) decay, so the wrapper folds the buckets into the
-    decayed weights with one masked scatter — see ops.ell_fleet_half_step."""
+    d-block ``block_ids[i, j]`` (sentinel buckets are zeros); with (m, B, C)
+    class coefficients (m, n_blocks_max, C_pad, blk_d), a row per class.
+    Unlike the sweep kernel it neither reads w nor applies the Pegasos axpy:
+    untouched blocks still need the (1 − λα) decay, so the wrapper folds the
+    buckets into the decayed weights with one masked scatter — see
+    ops.ell_fleet_half_step."""
     m, B, k = cols.shape
     n_blocks_max = block_ids.shape[1]
+    c_pad = 1 if coeff.ndim == 2 else class_pad(coeff.shape[2])
     kern = functools.partial(_ell_grad_prefetch_kernel, blk_d=blk_d,
                              n_d_blocks=n_d_blocks,
                              n_rows=B if n_rows is None else n_rows)
@@ -313,20 +368,20 @@ def ell_grad_update_prefetch(cols: jax.Array, vals: jax.Array,
         grid=(m, n_blocks_max),
         in_specs=[
             pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
-            pl.BlockSpec((1, B, k), lambda i, j, b: (i, 0, 0)),
+            pl.BlockSpec((1, B * c_pad, k), lambda i, j, b: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, blk_d), lambda i, j, b: (i, j, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, c_pad, blk_d), lambda i, j, b: (i, j, 0, 0)),
     )
     out = pl.pallas_call(
         kern,
         name="ell_fleet_half_step_update",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n_blocks_max, 1, blk_d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, n_blocks_max, c_pad, blk_d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_ids, cols, coeff[:, :, None] * vals)
-    return out.reshape(m, n_blocks_max, blk_d)
+    )(block_ids, cols, _class_contrib(coeff, vals))
+    return out if coeff.ndim == 3 else out.reshape(m, n_blocks_max, blk_d)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,8 @@ Every other test runs the kernels in interpret mode, which accepts block
 shapes and vector ops that the chip's compiler refuses. These tests hand each
 dispatch wrapper to the TPU compiler for a described (not attached) v5e chip
 at the deployment widths — CCAT (m = 10, B = 1, k = 76, d = 47,236; its
-objective pass over the whole 781,270-row partitions) and webspam (d = 254) —
+objective pass over the whole 781,270-row partitions), webspam (d = 254) and
+rcv1.multiclass (CCAT's widths, C = 53 one-vs-rest classes) —
 and assert that the compiled program holds the Mosaic kernel
 (``tpu_custom_call``). A refused block layout, shape cast or VMEM overrun
 fails here, without a chip. Nothing runs, so no result is checked.
@@ -25,6 +26,7 @@ from repro.kernels.hinge_subgrad import ops
 WIDTHS = {
     "ccat": dict(m=10, B=1, k=76, d=47236),
     "webspam": dict(m=10, B=1, k=84, d=254),
+    "rcv1_multiclass": dict(m=10, B=1, k=76, d=47236, C=53),
 }
 LAM, T = 1e-4, 3.0
 
@@ -95,15 +97,16 @@ def test_local_half_step_compiles(chip):
 
 @pytest.mark.parametrize("one_node", [False, True])  # True: a mesh step's shard
 @pytest.mark.parametrize("schedule", ["sweep", "prefetch"])
-@pytest.mark.parametrize("name", ["ccat", "webspam"])
+@pytest.mark.parametrize("name", ["ccat", "webspam", "rcv1_multiclass"])
 def test_ell_fleet_half_step_compiles(chip, name, schedule, one_node):
     m, B, k, d = (WIDTHS[name][x] for x in ("m", "B", "k", "d"))
     m = 1 if one_node else m
+    classes = (WIDTHS[name]["C"],) if "C" in WIDTHS[name] else ()
     compile_for_chip(
         lambda W, c, v, y: ops.ell_fleet_half_step(
             W, c, v, y, lam=LAM, t=T, interpret=False, schedule=schedule),
-        spec(chip, (m, d)), spec(chip, (m, B, k), jnp.int32),
-        spec(chip, (m, B, k)), spec(chip, (m, B)))
+        spec(chip, (m,) + classes + (d,)), spec(chip, (m, B, k), jnp.int32),
+        spec(chip, (m, B, k)), spec(chip, (m, B) + classes))
 
 
 def test_ell_objective_compiles(chip):
@@ -152,4 +155,21 @@ def test_training_chunk_compiles(chip, monkeypatch):
         (spec(chip, (m, n_i, k), jnp.int32), spec(chip, (m, n_i, k))),
         spec(chip, (m, n_i)), None, key, key, spec(chip, (m,)),
         spec(chip, (m, d)), spec(chip, (m, d))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_class_segment_compiles(chip, monkeypatch):
+    """One stream segment at rcv1.multiclass's widths, C = 53 classes (rows
+    per node cut): the class-axis kernels, the (m, C·d) mix and the blocked
+    class objective pass in one program."""
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    m, k, d, n_i, C = 10, 76, 47236, 1024, 53
+    cfg = PAPER_RUNS["ccat"].gadget._replace(use_kernels=True, max_iters=20)
+    segment = gadget._make_segment_train.__wrapped__(
+        gadget._cache_cfg(cfg), m, n_i, d, 10, sparse_block_bound=k)
+    key = spec(chip, (2,), jnp.uint32)
+    compiled = segment.lower(
+        (spec(chip, (m, n_i, k), jnp.int32), spec(chip, (m, n_i, k))),
+        spec(chip, (m, n_i, C)), None, key, key, spec(chip, (m,)),
+        spec(chip, (m, C, d)), spec(chip, (m, C, d)), spec(chip, (), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
